@@ -32,6 +32,7 @@ import json
 import jax
 
 from repro.core.partition import PartitionConfig
+from repro.launch.compile_cache import cache_stats, enable_compile_cache
 
 # occupancy is structural (same workload -> same batches) so the default
 # cut-style tolerance applies; throughput is wall-clock on shared CI
@@ -54,7 +55,7 @@ SMOKE_SPEC = {
 }
 
 
-def _smoke_serve_cfg(backend: str, compile_cache=None):
+def _smoke_serve_cfg(backend: str):
     from repro.launch.partition_serve import ServeConfig
 
     pcfg = PartitionConfig(k=4, backend=backend, coarse_target=32,
@@ -62,13 +63,12 @@ def _smoke_serve_cfg(backend: str, compile_cache=None):
     # window >> the burst's arrival span, so a slow CI runner still
     # coalesces the whole burst into one deterministic batch
     return ServeConfig(ladder_n=192, ladder_m=1280, window_s=0.025, lanes=2,
-                       partition=pcfg, compile_cache=compile_cache)
+                       partition=pcfg)
 
 
 def serve_smoke(backends=("dense", "sorted", "ell"),
-                json_path="BENCH_serve.json", compile_cache=None):
+                json_path="BENCH_serve.json"):
     """The CI serving gate; returns the (written) report dict."""
-    from repro.launch.partition_serve import cache_stats
     from repro.launch.serve_cli import run_workload
 
     # merge into an existing report (bench_partitioner smoke convention):
@@ -85,7 +85,7 @@ def serve_smoke(backends=("dense", "sorted", "ell"),
         # first) would contaminate — same discipline as fleet_ab
         jax.clear_caches()
         cache0 = cache_stats().snapshot()
-        rep = run_workload(_smoke_serve_cfg(backend, compile_cache),
+        rep = run_workload(_smoke_serve_cfg(backend),
                            SMOKE_SPEC, warmup=True, verify=True)
         occ = {int(kk): vv
                for kk, vv in rep["server"]["occupancy_hist"].items()}
@@ -217,15 +217,13 @@ def main():
                     help="CI serving gate: tiny burst, all gates on")
     ap.add_argument("--backends", default="dense,sorted,ell",
                     help="comma-separated backend list for --smoke")
-    ap.add_argument("--compile-cache", default=None,
-                    help="JAX persistent compilation cache directory")
     ap.add_argument("--json", default="BENCH_serve.json")
     a = ap.parse_args()
     if not a.smoke:
         ap.error("only --smoke is implemented; use serve_cli for ad-hoc "
                  "replays")
-    serve_smoke(backends=tuple(a.backends.split(",")), json_path=a.json,
-                compile_cache=a.compile_cache)
+    enable_compile_cache()
+    serve_smoke(backends=tuple(a.backends.split(",")), json_path=a.json)
     return 0
 
 

@@ -68,7 +68,7 @@ def run_variant(cell_name: str, variant: str, out_dir="artifacts/perf"):
     t0 = time.perf_counter()
     try:
         cell = build_cell(arch, spec["shape"], mesh, tuning=dict(tuning))
-        with mesh:
+        with jax.set_mesh(mesh):
             compiled = jax.jit(
                 cell.step_fn, in_shardings=cell.in_shardings,
                 out_shardings=cell.out_shardings,

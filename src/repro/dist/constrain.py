@@ -10,10 +10,9 @@ other names are physical mesh axes and are dropped when the mesh lacks
 them.  With no active mesh — unit tests, single-host CPU runs — every
 call returns its input unchanged, so the zoo stays runnable anywhere.
 
-The active mesh is either the innermost ``with mesh:`` scope (JAX's
-thread-local mesh context) or an explicit :func:`constraint_mesh` scope,
-which also works around jit boundaries where the context manager does not
-reach.
+The active mesh is either an explicit :func:`constraint_mesh` scope or
+the mesh of the innermost ``jax.set_mesh`` scope, which JAX carries into
+traced functions as an abstract mesh.
 """
 from __future__ import annotations
 
@@ -39,13 +38,8 @@ def current_mesh():
     """The mesh constrain() resolves against, or None."""
     if _MESH_STACK:
         return _MESH_STACK[-1]
-    try:  # `with mesh:` scope (thread-local physical mesh)
-        mesh = jax.interpreters.pxla.thread_resources.env.physical_mesh
-        if not mesh.empty:
-            return mesh
-    except AttributeError:
-        pass
-    return None
+    mesh = jax.sharding.get_abstract_mesh()  # `jax.set_mesh` scope
+    return None if mesh.empty else mesh
 
 
 def _resolve(axis, mesh_axes):

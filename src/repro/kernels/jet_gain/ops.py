@@ -12,8 +12,29 @@ from repro.kernels.jet_gain.jet_gain import jet_gain_pallas
 from repro.kernels.jet_gain.ref import jet_gain_ref
 
 
+# v5e default scoped VMEM.  Per grid step the kernel holds its two int32
+# (block_n, D) inputs double-buffered plus two (block_n, D) temporaries of
+# the k-sweep (the part-compare mask and the masked weights), with D padded
+# to the 128-lane tile.
+_VMEM_BYTES = 16 * 1024 * 1024
+_TILES_PER_ROW = 2 * 2 + 2
+
+
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def block_rows(d: int) -> int:
+    """Rows per kernel grid step for ELL width ``d``: the largest multiple
+    of 8, at most 256, whose VMEM tiles fit the scoped VMEM."""
+    d_lanes = -(-d // 128) * 128
+    rows = min(256, _VMEM_BYTES // (_TILES_PER_ROW * d_lanes * 4) // 8 * 8)
+    if rows < 8:
+        raise ValueError(
+            f"ELL width {d} is too wide for the jet_gain kernel: even 8 rows "
+            f"of its VMEM tiles exceed {_VMEM_BYTES >> 20} MiB"
+        )
+    return rows
 
 
 def csr_to_ell(g, max_degree: int | None = None):
@@ -66,20 +87,21 @@ def ell_to_matrix(nbr_parts, wgt, k: int):
     return mat.at[rows, nbr_parts].add(wgt)
 
 
-def jet_gain_from_parts(nbr_parts, wgt, parts, k: int, block_n: int = 256,
-                        use_pallas=None):
+def jet_gain_from_parts(nbr_parts, wgt, parts, k: int, use_pallas=None):
     """Fused conn_self / best_part / best_conn from precomputed neighbor
     parts — the entry point for the stateful ELL backend.
 
     ``use_pallas=None`` auto-selects: the compiled kernel on TPU, the
     bit-identical pure-jnp k-sweep elsewhere (interpret-mode Pallas is for
-    kernel validation, not production CPU runs).
+    kernel validation, not production CPU runs).  The row tile comes from
+    the ELL width (:func:`block_rows`).
     """
     n, d = nbr_parts.shape
     if use_pallas is None:
         use_pallas = _on_tpu()
     if not use_pallas:
         return jet_gain_ref(nbr_parts, wgt, parts, k)
+    block_n = block_rows(d)
     pad = (-n) % block_n
     if pad:
         nbr_parts = jnp.pad(nbr_parts, ((0, pad), (0, 0)), constant_values=k)
@@ -91,7 +113,7 @@ def jet_gain_from_parts(nbr_parts, wgt, parts, k: int, block_n: int = 256,
     return cs[:n], bp[:n], bc[:n]
 
 
-def jet_gain(nbr, wgt, parts, k: int, block_n: int = 256, use_pallas=None):
+def jet_gain(nbr, wgt, parts, k: int, use_pallas=None):
     """Fused conn_self / best_part / best_conn (see jet_gain.py).
 
     ``nbr`` holds neighbor ids; part ids are looked up here (outside the
@@ -99,5 +121,5 @@ def jet_gain(nbr, wgt, parts, k: int, block_n: int = 256, use_pallas=None):
     maps to ghost part k.
     """
     nbr_parts = lookup_nbr_parts(nbr, parts, k)
-    return jet_gain_from_parts(nbr_parts, wgt, parts, k, block_n=block_n,
+    return jet_gain_from_parts(nbr_parts, wgt, parts, k,
                                use_pallas=use_pallas)

@@ -110,7 +110,7 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool,
             out_shardings=cell.out_shardings,
             donate_argnums=cell.donate,
         )
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(*cell.args)
             rec["lower_s"] = time.perf_counter() - t0
             t1 = time.perf_counter()

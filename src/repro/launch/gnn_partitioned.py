@@ -32,21 +32,6 @@ from repro.models.gnn.common import mlp_apply
 from repro.optim import adamw
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions (jax.shard_map landed after 0.4.x)."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:  # pre-rename releases take check_rep instead
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as sm
-
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
-
-
 def _sizes(shape, mesh, halo_frac: float):
     n = _pad512(shape.get("n_nodes", shape.get("pad_nodes")))
     e = _pad512(shape.get("n_edges", shape.get("pad_edges")))
@@ -245,7 +230,8 @@ def partitioned_gnn_cell(arch, shape_name, mesh, smoke=False, tuning=None):
         {k: P(axes, *([None] * (len(v.shape) - 1)))
          for k, v in batch_sds.items()},
     )
-    shard_loss = _shard_map(local_loss, mesh, in_specs, P())
+    shard_loss = jax.shard_map(local_loss, mesh=mesh, in_specs=in_specs,
+                               out_specs=P(), check_vma=False)
 
     opt_cfg = adamw.AdamWConfig()
 

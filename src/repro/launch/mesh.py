@@ -11,29 +11,21 @@ state (the dry-run sets XLA_FLAGS before any jax import).
 from __future__ import annotations
 
 import jax
-
-
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: AxisType (and the axis_types
-    kwarg) only exist on newer jax; older versions default to Auto."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(
-        shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model_axis: int = 1):
     """Tiny mesh over the locally available devices (tests / smoke runs)."""
     n = len(jax.devices())
     data = max(1, n // model_axis)
-    return compat_make_mesh((data, model_axis), ("data", "model"))
+    return jax.make_mesh((data, model_axis), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def dp_axes(mesh) -> tuple:

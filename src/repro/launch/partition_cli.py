@@ -26,6 +26,7 @@ import numpy as np
 from repro.core.partition import PartitionConfig, partition, partition_fleet
 from repro.core.graph import build_csr_host
 from repro.data import graphs as gen
+from repro.launch.compile_cache import enable_compile_cache
 
 GRAPH_KINDS = ("grid", "cube", "rmat", "geo", "smallworld", "edgelist")
 
@@ -131,6 +132,7 @@ def main(argv=None):
     ap.add_argument("--out", default=None, help="write parts as .npy "
                     "(single-graph mode only)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     trial_seeds = (
         tuple(int(s) for s in args.trial_seeds.split(","))

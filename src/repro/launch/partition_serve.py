@@ -14,9 +14,10 @@ waits.  This module adds the missing traffic layer:
 
 * Warm-start subsystem: :meth:`PartitionServer.warmup` is an explicit AOT
   pass that precompiles the (rung, k) signature grid from representative
-  shapes, and :func:`enable_compile_cache` wires JAX's persistent
-  compilation cache so a cold process re-reaches steady-state latency
-  from disk instead of from XLA.
+  shapes; with JAX's persistent compilation cache on
+  (:mod:`repro.launch.compile_cache`, which the entry points enable), a
+  cold process re-reaches steady-state latency from disk instead of from
+  XLA.
 
 Batch width discipline: every dispatched bucket is padded (with filler
 copies of its first member) or split to exactly ``ServeConfig.lanes``
@@ -36,78 +37,13 @@ from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field, replace
 
-import jax
-
 from repro.core import graph as gr
 from repro.core.coarsen import _round_up, shape_schedule
 from repro.core.partition import (
     PartitionConfig, PartitionResult, partition_fleet_stacked,
     uncoarsen_level_fleet,
 )
-
-
-# ---------------------------------------------------------------------------
-# Persistent compilation cache (warm starts across processes)
-# ---------------------------------------------------------------------------
-
-class CompileCacheStats:
-    """Counter sink for JAX's compilation-cache monitoring events.
-
-    XLA emits ``/jax/compilation_cache/cache_hits`` / ``cache_misses``
-    events only when the persistent cache is enabled; a miss is a real
-    XLA compile, a hit is an executable deserialized from disk.  The
-    serve bench gates "zero new executables after warmup" on the miss
-    delta.
-    """
-
-    def __init__(self):
-        self.counts: dict[str, int] = {}
-
-    def __call__(self, name: str, **kw) -> None:
-        if name.startswith("/jax/compilation_cache/"):
-            key = name.rsplit("/", 1)[-1]
-            self.counts[key] = self.counts.get(key, 0) + 1
-
-    def snapshot(self) -> dict[str, int]:
-        return dict(self.counts)
-
-    @staticmethod
-    def delta(before: dict, after: dict) -> dict[str, int]:
-        return {k: after.get(k, 0) - before.get(k, 0)
-                for k in set(before) | set(after)}
-
-
-_CACHE_STATS: CompileCacheStats | None = None
-
-
-def cache_stats() -> CompileCacheStats:
-    """The process-wide event listener (registered once, lazily)."""
-    global _CACHE_STATS
-    if _CACHE_STATS is None:
-        _CACHE_STATS = CompileCacheStats()
-        jax.monitoring.register_event_listener(_CACHE_STATS)
-    return _CACHE_STATS
-
-
-def enable_compile_cache(cache_dir: str) -> CompileCacheStats:
-    """Point JAX's persistent compilation cache at ``cache_dir``.
-
-    Thresholds are dropped to zero so every executable persists — the
-    partitioner's per-rung programs are small but numerous, exactly the
-    population the default min-compile-time filter would skip.  Returns
-    the hit/miss counter listener.
-    """
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    stats = cache_stats()  # register BEFORE the first compile
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    # any jit that ran before this call (repro modules compile helpers at
-    # import) memoizes the cache object as "disabled"; reset so the new
-    # dir takes effect
-    cc.reset_cache()
-    return stats
+from repro.launch.compile_cache import CompileCacheStats, cache_stats
 
 
 # ---------------------------------------------------------------------------
@@ -131,7 +67,6 @@ class ServeConfig:
     lanes: int = 4
     max_batch: int = 64            # requests per coalesce round, max
     partition: PartitionConfig = field(default_factory=PartitionConfig)
-    compile_cache: str | None = None
 
 
 @dataclass
@@ -182,8 +117,6 @@ class PartitionServer:
             ratio=p.bucket_ratio, safety=p.bucket_safety,
             stall_ratio=p.stall_ratio, align=p.bucket_align,
         )
-        if cfg.compile_cache:
-            enable_compile_cache(cfg.compile_cache)
         self._queue: asyncio.Queue | None = None
         self._task: asyncio.Task | None = None
         self._pool: ThreadPoolExecutor | None = None

@@ -29,6 +29,7 @@ import time
 import numpy as np
 
 from repro.core.partition import PartitionConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.partition_cli import _make_graph, _parse_fleet_spec
 from repro.launch.partition_serve import (
     PartitionServer, ServeConfig, serve_signatures,
@@ -240,10 +241,9 @@ def main(argv=None):
     ap.add_argument("--verify", action="store_true",
                     help="assert every response is bit-identical to a "
                          "standalone partition() run")
-    ap.add_argument("--compile-cache", default=None,
-                    help="JAX persistent compilation cache directory")
     ap.add_argument("--json", default=None, help="write the report here")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.workload:
         with open(args.workload) as f:
@@ -268,7 +268,7 @@ def main(argv=None):
                            coarse_target=args.coarse_target, seed=args.seed)
     scfg = ServeConfig(ladder_n=args.ladder_n, ladder_m=args.ladder_m,
                        window_s=args.window_ms / 1e3, lanes=args.lanes,
-                       partition=pcfg, compile_cache=args.compile_cache)
+                       partition=pcfg)
     try:
         report = run_workload(scfg, spec, warmup=not args.no_warmup,
                               verify=args.verify, workload=workload)

@@ -7,6 +7,10 @@
   only the matching parametrization (unparametrized tests always run).
   CI matrixes its tier-1 job over this variable so the three backends run
   in parallel lanes instead of serially in one.
+* Entry points turn on JAX's persistent compilation cache
+  (``repro.launch.compile_cache``).  Inside a test it goes to a per-worker
+  temp directory, and the cache settings are restored after the test, so
+  no test writes into the checkout or changes what later tests compile.
 """
 from __future__ import annotations
 
@@ -14,7 +18,31 @@ import os
 import sys
 from pathlib import Path
 
+import jax
+import pytest
+
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+
+_CACHE_OPTIONS = (
+    "jax_compilation_cache_dir",
+    "jax_enable_compilation_cache",
+    "jax_persistent_cache_min_entry_size_bytes",
+    "jax_persistent_cache_min_compile_time_secs",
+)
+
+
+@pytest.fixture(autouse=True)
+def _scoped_compile_cache(monkeypatch, tmp_path_factory):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                       str(tmp_path_factory.getbasetemp() / "jax-cache"))
+    before = {o: getattr(jax.config, o) for o in _CACHE_OPTIONS}
+    yield
+    if any(getattr(jax.config, o) != v for o, v in before.items()):
+        from jax.experimental.compilation_cache import compilation_cache as cc
+
+        for o, v in before.items():
+            jax.config.update(o, v)
+        cc.reset_cache()
 
 _BACKENDS = ("dense", "sorted", "ell")
 

@@ -45,8 +45,8 @@ ref_batch = {
 ref_loss = float(meshgraphnet.loss_fn(cfg, params, ref_batch)[0])
 
 # partitioned loss under shard_map on an 8-device mesh
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 n_l = 64  # 256/8 = 32; pad blocks to 64 for slack
 h_cap = 64
 e_cap_total = 2048

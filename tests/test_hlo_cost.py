@@ -12,11 +12,6 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
-def _xla_cost(compiled) -> dict:
-    ca = compiled.cost_analysis()
-    return ca[0] if isinstance(ca, list) else ca  # list-of-dict on old jax
-
-
 def test_matmul_flops_exact():
     a = jax.ShapeDtypeStruct((128, 256), jnp.float32)
     b = jax.ShapeDtypeStruct((256, 512), jnp.float32)
@@ -25,7 +20,7 @@ def test_matmul_flops_exact():
     want = 2 * 128 * 256 * 512
     assert got["flops"] == pytest.approx(want, rel=0.05), got["flops"]
     # agrees with XLA on a loop-free program
-    xla = _xla_cost(c)["flops"]
+    xla = c.cost_analysis()["flops"]
     assert got["flops"] == pytest.approx(xla, rel=0.05)
 
 
@@ -82,6 +77,6 @@ def test_layers_scale_in_model_flops():
             return jax.tree.map(lambda t: jnp.sum(t.astype(jnp.float32)), g)
         c = _compile(grad, p, b)
         flops[L] = analyze_hlo(c.as_text())["flops"]
-        assert flops[L] != pytest.approx(_xla_cost(c)["flops"]) or L == 2
+        assert flops[L] != pytest.approx(c.cost_analysis()["flops"]) or L == 2
     ratio = flops[4] / flops[2]
     assert 1.3 < ratio < 2.2, flops

@@ -6,7 +6,7 @@ import pytest
 from repro.core import connectivity as cn
 from repro.data import graphs as gen
 from repro.kernels.jet_gain.jet_gain import jet_gain_pallas
-from repro.kernels.jet_gain.ops import csr_to_ell, jet_gain
+from repro.kernels.jet_gain.ops import csr_to_ell, jet_gain, jet_gain_from_parts
 from repro.kernels.jet_gain.ref import jet_gain_ref
 
 
@@ -72,3 +72,24 @@ def test_ell_path_matches_csr_connectivity(name):
     np.testing.assert_array_equal(np.asarray(cs)[:n], np.asarray(q.conn_self)[:n])
     np.testing.assert_array_equal(np.asarray(bc)[:n], np.asarray(q.best_conn)[:n])
     np.testing.assert_array_equal(np.asarray(bp)[:n], np.asarray(q.best_part)[:n])
+
+
+def test_block_rows_fits_vmem():
+    """Row tile shrinks with the ELL width; too-wide rows fail loudly."""
+    from repro.kernels.jet_gain.ops import block_rows
+
+    assert block_rows(8) == 256
+    assert block_rows(1492) == 256
+    assert block_rows(20000) == 32
+    assert all(block_rows(d) % 8 == 0 for d in (1, 300, 5000, 80000))
+    with pytest.raises(ValueError, match="too wide"):
+        block_rows(100_000)
+
+
+def test_derived_block_matches_ref_on_wide_rows():
+    """A width whose derived tile is not 256, with n not a tile multiple."""
+    nbr_parts, nwgt, parts = _rand_inputs(100, 5000, 6, seed=5)
+    want = jet_gain_ref(nbr_parts, nwgt, parts, 6)
+    got = jet_gain_from_parts(nbr_parts, nwgt, parts, 6, use_pallas=True)
+    for w, g_ in zip(want, got):
+        np.testing.assert_array_equal(np.asarray(w), np.asarray(g_))
