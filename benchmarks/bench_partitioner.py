@@ -108,8 +108,6 @@ def time_breakdown(quick=False):
         tot = res.times["total_s"]
         rows.append((f"breakdown/{name}/coarsen_pct",
                      100 * res.times["coarsen_s"] / tot))
-        rows.append((f"breakdown/{name}/initpart_pct",
-                     100 * res.times["initpart_s"] / tot))
         rows.append((f"breakdown/{name}/uncoarsen_pct",
                      100 * res.times["uncoarsen_s"] / tot))
         rows.append((f"breakdown/{name}/total_s", tot))
@@ -148,8 +146,7 @@ def coarsen_mode_ab(names=None, k=16, coarse_target=1024, reps=2,
                 "cold": res.times,
                 "warm": {
                     ph: float(np.mean([t.times[ph] for t in timed]))
-                    for ph in ("coarsen_s", "initpart_s", "uncoarsen_s",
-                               "total_s")
+                    for ph in ("coarsen_s", "uncoarsen_s", "total_s")
                 },
                 "level_capacity": [
                     (st["n"], st["m"], st["n_max"], st["m_max"])

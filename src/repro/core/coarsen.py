@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.graph import Graph, csr_from_edge_runs
+from repro.core.spans import span
 
 _KNUTH = jnp.uint32(2654435761)
 
@@ -349,8 +350,9 @@ def _rebucket(g: Graph, n_max: int, m_max: int) -> Graph:
     return g.with_capacity(n_max, m_max)
 
 
-def _fetch_stats(g: Graph) -> dict:
-    n, m, max_deg = (int(x) for x in np.asarray(_level_stats_dev(g)))
+def _fetch_stats(g: Graph, level: int = 0) -> dict:
+    with span("coarsen.fetch", level=level):
+        n, m, max_deg = (int(x) for x in np.asarray(_level_stats_dev(g)))
     return {"n": n, "m": m, "max_degree": max_deg,
             "n_max": g.n_max, "m_max": g.m_max}
 
@@ -509,7 +511,8 @@ def multilevel_coarsen_fleet(
     """
     B = gb.vwgt.shape[0]
     n_max, m_max = gb.vwgt.shape[1], gb.adjncy.shape[1]
-    st0 = np.asarray(_stats_fleet(gb))
+    with span("coarsen.fetch", level=0):
+        st0 = np.asarray(_stats_fleet(gb))
     n, m, md = (st0[:, j].astype(np.int64) for j in range(3))
     if schedule[0][0] < n_max or schedule[0][1] < m_max:
         raise ValueError(
@@ -523,22 +526,26 @@ def multilevel_coarsen_fleet(
         active = ~dead & (n > coarse_target)
         if not active.any():
             break
-        gc, cmap, stc = _coarsen_step_fleet(
-            gb, seed + lvl, twohop_threshold, mm_max_degree
-        )
-        stc = np.asarray(stc).astype(np.int64)  # the per-level host sync
-        stalled = stc[:, 0] > stall_ratio * n
-        success = active & ~stalled
-        dead |= active & stalled
-        if not success.any():
-            break
-        new_n = np.where(success, stc[:, 0], n)
-        new_m = np.where(success, stc[:, 1], m)
-        new_md = np.where(success, stc[:, 2], md)
-        cap = select_capacity(schedule, int(new_n.max()), int(new_m.max()))
-        gb2, cmap = _freeze_rebucket_fleet(
-            gc, cmap, gb, jnp.asarray(success), n_max=cap[0], m_max=cap[1]
-        )
+        with span("coarsen.level", level=lvl, n_max=n_max, m_max=m_max):
+            gc, cmap, stc = _coarsen_step_fleet(
+                gb, seed + lvl, twohop_threshold, mm_max_degree
+            )
+            with span("coarsen.fetch", level=lvl + 1):
+                stc = np.asarray(stc).astype(np.int64)  # per-level host sync
+            stalled = stc[:, 0] > stall_ratio * n
+            success = active & ~stalled
+            dead |= active & stalled
+            if not success.any():
+                break
+            new_n = np.where(success, stc[:, 0], n)
+            new_m = np.where(success, stc[:, 1], m)
+            new_md = np.where(success, stc[:, 2], md)
+            cap = select_capacity(schedule, int(new_n.max()),
+                                  int(new_m.max()))
+            gb2, cmap = _freeze_rebucket_fleet(
+                gc, cmap, gb, jnp.asarray(success), n_max=cap[0],
+                m_max=cap[1]
+            )
         raw.append((gb, cmap,
                     {"n": n, "m": m, "max_degree": md,
                      "n_max": n_max, "m_max": m_max}))
@@ -609,13 +616,13 @@ def multilevel_coarsen(
                 fine, twohop_threshold=twohop_threshold,
                 mm_max_degree=mm_max_degree, seed=seed + lvl,
             )
-            return gc, cmap, _fetch_stats(gc)
+            return gc, cmap, _fetch_stats(gc, lvl + 1)
         gc, cmap = coarsen_level(
             fine, seed=seed + lvl, twohop_threshold=twohop_threshold,
             mm_max_degree=mm_max_degree,
         )
         # The ONLY device-path host sync: 3 int32 (termination + capacity).
-        st = _fetch_stats(gc)
+        st = _fetch_stats(gc, lvl + 1)
         cap = select_capacity(schedule, st["n"], st["m"])
         if cap != (gc.n_max, gc.m_max):
             gc = _rebucket(gc, *cap)
@@ -627,7 +634,9 @@ def multilevel_coarsen(
     for lvl in range(max_levels):
         if stats["n"] <= coarse_target:
             break
-        gc, cmap, stats_c = step(cur, lvl)
+        with span("coarsen.level", level=lvl, n_max=cur.n_max,
+                  m_max=cur.m_max):
+            gc, cmap, stats_c = step(cur, lvl)
         if stats_c["n"] > stall_ratio * stats["n"]:  # stalled
             break
         levels.append(CoarsenLevel(graph=cur, cmap=cmap, stats=stats))

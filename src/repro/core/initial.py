@@ -132,7 +132,8 @@ def initial_partition(g: Graph, k: int, seed=0, method: str = "voronoi"):
 @partial(jax.jit, static_argnames=("k", "method"))
 def _initial_batch(g: Graph, seeds: jnp.ndarray, k: int, method: str):
     fn = random_partition if method == "random" else voronoi_partition
-    return jax.vmap(lambda s: fn(g, k, s))(seeds)
+    with jax.named_scope("initial"):
+        return jax.vmap(lambda s: fn(g, k, s))(seeds)
 
 
 def initial_partition_batch(
@@ -156,7 +157,9 @@ def initial_partition_batch(
 @partial(jax.jit, static_argnames=("k", "method"))
 def _initial_fleet(gb: Graph, seeds: jnp.ndarray, k: int, method: str):
     fn = random_partition if method == "random" else voronoi_partition
-    return jax.vmap(lambda g: jax.vmap(lambda s: fn(g, k, s))(seeds))(gb)
+    with jax.named_scope("initial"):
+        return jax.vmap(
+            lambda g: jax.vmap(lambda s: fn(g, k, s))(seeds))(gb)
 
 
 def initial_partition_fleet(

@@ -28,6 +28,7 @@ from repro.core import coarsen as co
 from repro.core import connectivity as cn
 from repro.core import graph as gr
 from repro.core import initial, metrics, refine
+from repro.core.spans import span
 
 
 @dataclass
@@ -105,9 +106,12 @@ def _uncoarsen_trials(
     """
 
     def one_trial(parts_coarse):
-        parts = co.project_partition(cmap, parts_coarse)
-        parts = jnp.where(fine.vertex_mask(), parts, k).astype(jnp.int32)
-        conn0 = cn.build_state(fine, parts, k, backend, max_degree=max_degree)
+        with jax.named_scope("uncoarsen.project"):
+            parts = co.project_partition(cmap, parts_coarse)
+            parts = jnp.where(fine.vertex_mask(), parts, k).astype(jnp.int32)
+        with jax.named_scope("uncoarsen.build_state"):
+            conn0 = cn.build_state(fine, parts, k, backend,
+                                   max_degree=max_degree)
         return refine._refine_loop(
             fine, parts, conn0, phi,
             k=k, lam=lam, c=c, backend=backend, patience=patience,
@@ -291,13 +295,21 @@ def partition_fleet_stacked(
     """
     if not buckets:
         raise ValueError("partition_fleet_stacked needs at least one bucket")
+    with span("partition_fleet",
+              n_max=max(sb.capacity[0] for sb in buckets),
+              m_max=max(sb.capacity[1] for sb in buckets), k=cfg.k,
+              trials=cfg.trials, buckets=len(buckets)):
+        return _partition_fleet_stacked(buckets, cfg, schedule, times_extra)
+
+
+def _partition_fleet_stacked(buckets, cfg, schedule, times_extra):
     k = cfg.k
     seeds = _resolve_trial_seeds(cfg)
     trials = cfg.trials
-    times = {"coarsen_s": 0.0, "initpart_s": 0.0, "uncoarsen_s": 0.0,
-             "fetch_s": 0.0}
+    times = {"coarsen_s": 0.0, "uncoarsen_s": 0.0, "fetch_s": 0.0}
     if times_extra:  # e.g. the wrapper's admission/bucketing time, so
         times.update(times_extra)  # member times keep the full accounting
+    t_start = time.perf_counter()
 
     pending = []  # (bucket record, metas, fetch pytree, device parts_bt)
     for sb in buckets:
@@ -306,84 +318,83 @@ def partition_fleet_stacked(
         B = len(idxs)
         gb = sb.graph
 
-        t0 = time.perf_counter()
-        levels = co.multilevel_coarsen_fleet(
-            gb, schedule,
-            coarse_target=cfg.coarse_target, max_levels=cfg.max_levels,
-            stall_ratio=cfg.stall_ratio, seed=cfg.seed,
-        )
-        times["coarsen_s"] += time.perf_counter() - t0
-
-        t0 = time.perf_counter()
-        parts_bt = initial.initial_partition_fleet(
-            levels[-1].graph, k, seeds, method=cfg.init_method
-        )
-        times["initpart_s"] += time.perf_counter() - t0
-
-        t0 = time.perf_counter()
-        stats_per_level = []
-        metas = []
-        for i in range(len(levels) - 1, -1, -1):
-            lv = levels[i]
-            gi = lv.graph
-            c = cfg.c_finest if i == 0 else cfg.c_coarse
-            # static ELL width: max over lanes, from the coarsening stats —
-            # frozen lanes are included (their build_state runs too)
-            max_deg = (
-                int(lv.stats["max_degree"].max()) if cfg.backend == "ell"
-                else None
+        with span("partition.coarsen", times, "coarsen_s"):
+            levels = co.multilevel_coarsen_fleet(
+                gb, schedule,
+                coarse_target=cfg.coarse_target, max_levels=cfg.max_levels,
+                stall_ratio=cfg.stall_ratio, seed=cfg.seed,
             )
-            n_cap_i = gi.vwgt.shape[1]
-            if i == len(levels) - 1:
-                cmap = jnp.broadcast_to(
-                    jnp.arange(n_cap_i, dtype=jnp.int32), (B, n_cap_i)
+
+        with span("partition.initial"):
+            parts_bt = initial.initial_partition_fleet(
+                levels[-1].graph, k, seeds, method=cfg.init_method
+            )
+
+        with span("partition.uncoarsen", times, "uncoarsen_s"):
+            stats_per_level = []
+            metas = []
+            for i in range(len(levels) - 1, -1, -1):
+                lv = levels[i]
+                gi = lv.graph
+                c = cfg.c_finest if i == 0 else cfg.c_coarse
+                # static ELL width: max over lanes, from the coarsening stats —
+                # frozen lanes are included (their build_state runs too)
+                max_deg = (
+                    int(lv.stats["max_degree"].max()) if cfg.backend == "ell"
+                    else None
                 )
-            else:
-                cmap = lv.cmap
-            parts_bt, stats = uncoarsen_level_fleet(
-                gi, cmap, parts_bt, jnp.asarray(lv.active), cfg.phi,
-                k=k, lam=cfg.lam, c=c, backend=cfg.backend,
-                patience=cfg.patience, max_iter=cfg.max_iter,
-                b_max=cfg.b_max, variant=cfg.variant,
-                rebuild_every=cfg.rebuild_every, max_degree=max_deg,
-            )
-            stats_per_level.append(stats)
-            meta = {
-                "level": i,
-                "n_max": lv.stats["n_max"], "m_max": lv.stats["m_max"],
-                "n": lv.stats["n"], "m": lv.stats["m"],
-                "max_degree": lv.stats["max_degree"],
-                "active": lv.active,
-            }
-            if max_deg is not None:
-                meta["ell_width"] = max_deg
-            metas.append(meta)
+                n_cap_i = gi.vwgt.shape[1]
+                if i == len(levels) - 1:
+                    cmap = jnp.broadcast_to(
+                        jnp.arange(n_cap_i, dtype=jnp.int32), (B, n_cap_i)
+                    )
+                else:
+                    cmap = lv.cmap
+                with span("uncoarsen.level", level=i,
+                          n_max=lv.stats["n_max"], m_max=lv.stats["m_max"]):
+                    parts_bt, stats = uncoarsen_level_fleet(
+                        gi, cmap, parts_bt, jnp.asarray(lv.active), cfg.phi,
+                        k=k, lam=cfg.lam, c=c, backend=cfg.backend,
+                        patience=cfg.patience, max_iter=cfg.max_iter,
+                        b_max=cfg.b_max, variant=cfg.variant,
+                        rebuild_every=cfg.rebuild_every, max_degree=max_deg,
+                    )
+                stats_per_level.append(stats)
+                meta = {
+                    "level": i,
+                    "n_max": lv.stats["n_max"], "m_max": lv.stats["m_max"],
+                    "n": lv.stats["n"], "m": lv.stats["m"],
+                    "max_degree": lv.stats["max_degree"],
+                    "active": lv.active,
+                }
+                if max_deg is not None:
+                    meta["ell_width"] = max_deg
+                metas.append(meta)
 
-        fstats = stats_per_level[-1]
-        ep = _fleet_epilogue(
-            levels[0].graph, parts_bt,
-            fstats["best_balanced"], fstats["best_cost"],
-            fstats["best_maxsize"], k=k, lam=cfg.lam,
-        )
-        fetch = {
-            "stats": {
-                kk: jnp.stack([s[kk] for s in stats_per_level])  # (L, B, T)
-                for kk in stats_per_level[0]
-            },
-            **ep,
-            "trial_cuts": fstats["best_cost"],        # (B, T)
-            "trial_balanced": fstats["best_balanced"],
-        }
+            fstats = stats_per_level[-1]
+            ep = _fleet_epilogue(
+                levels[0].graph, parts_bt,
+                fstats["best_balanced"], fstats["best_cost"],
+                fstats["best_maxsize"], k=k, lam=cfg.lam,
+            )
+            fetch = {
+                "stats": {  # (L, B, T)
+                    kk: jnp.stack([s[kk] for s in stats_per_level])
+                    for kk in stats_per_level[0]
+                },
+                **ep,
+                "trial_cuts": fstats["best_cost"],        # (B, T)
+                "trial_balanced": fstats["best_balanced"],
+            }
         bucket = FleetBucket(capacity=cap, indices=idxs, levels=len(levels),
                              level_stats=metas)
         pending.append((bucket, sb.orig_n_max, metas, fetch, parts_bt))
-        times["uncoarsen_s"] += time.perf_counter() - t0
 
     # the ONE blocking transfer of the whole fleet's uncoarsening phase
-    t0 = time.perf_counter()
-    host_all = jax.device_get([p[3] for p in pending])
-    times["fetch_s"] = time.perf_counter() - t0
-    times["total_s"] = sum(times.values())
+    with span("partition.fetch", times, "fetch_s"):
+        host_all = jax.device_get([p[3] for p in pending])
+    times["total_s"] = (sum((times_extra or {}).values())
+                        + time.perf_counter() - t_start)
 
     results: dict = {}
     out_buckets = []
@@ -502,39 +513,12 @@ def partition_fleet(graphs, cfg: PartitionConfig,
                        times=sres.times, trials=sres.trials, config=cfg)
 
 
-def partition(g, cfg: PartitionConfig) -> PartitionResult:
-    """Full multilevel partition of ``g`` into ``cfg.k`` parts.
-
-    With ``cfg.trials = T > 1``, the whole uncoarsening phase runs vmapped
-    over T seed trials on the shared hierarchy and the returned partition
-    is the device-selected best trial; ``trial_cuts`` / ``trial_balanced``
-    / ``trial_parts`` expose the full batch.  Trial ``t`` is bit-identical
-    to a ``trials=1`` run with ``trial_seeds=(seeds[t],)``.
-    """
+def _uncoarsen(g, levels, parts_b, cfg: PartitionConfig):
+    """The uncoarsening phase of :func:`partition`, from the coarsest
+    level's initial ``parts_b`` to the one blocking fetch.  Returns the
+    best trial's parts, the (T, n_max) parts batch, the fetched host
+    values and the per-level host metadata."""
     k = cfg.k
-    seeds = _resolve_trial_seeds(cfg)
-    trials = cfg.trials
-    t0 = time.perf_counter()
-    levels = co.multilevel_coarsen(
-        g,
-        coarse_target=cfg.coarse_target,
-        max_levels=cfg.max_levels,
-        stall_ratio=cfg.stall_ratio,
-        seed=cfg.seed,
-        mode=cfg.coarsen_mode,
-        bucket_ratio=cfg.bucket_ratio,
-        bucket_safety=cfg.bucket_safety,
-        bucket_align=cfg.bucket_align,
-    )
-    t_coarsen = time.perf_counter() - t0
-
-    t0 = time.perf_counter()
-    gc = levels[-1].graph
-    parts_b = initial.initial_partition_batch(gc, k, seeds,
-                                              method=cfg.init_method)
-    t_init = time.perf_counter() - t0
-
-    t0 = time.perf_counter()
     # refine coarsest, then uncoarsen.  Each level is ONE jitted
     # `uncoarsen_level` call (project -> mask -> ConnState build -> Alg 4.1
     # loop) vmapped over the trial axis; per-trial stats stay on device and
@@ -560,13 +544,15 @@ def partition(g, cfg: PartitionConfig) -> PartitionResult:
             cmap = jnp.arange(gi.n_max, dtype=jnp.int32)
         else:
             cmap = levels[i].cmap
-        parts_b, stats = uncoarsen_level(
-            gi, cmap, parts_b, cfg.phi,
-            k=k, lam=cfg.lam, c=c, backend=cfg.backend,
-            patience=cfg.patience, max_iter=cfg.max_iter, b_max=cfg.b_max,
-            variant=cfg.variant, rebuild_every=cfg.rebuild_every,
-            max_degree=max_deg,
-        )
+        with span("uncoarsen.level", level=i, n_max=gi.n_max,
+                  m_max=gi.m_max):
+            parts_b, stats = uncoarsen_level(
+                gi, cmap, parts_b, cfg.phi,
+                k=k, lam=cfg.lam, c=c, backend=cfg.backend,
+                patience=cfg.patience, max_iter=cfg.max_iter,
+                b_max=cfg.b_max, variant=cfg.variant,
+                rebuild_every=cfg.rebuild_every, max_degree=max_deg,
+            )
         stats_per_level.append(stats)
         meta = (
             {kk: lv_stats[kk] for kk in ("n", "m", "n_max", "m_max",
@@ -604,8 +590,53 @@ def partition(g, cfg: PartitionConfig) -> PartitionResult:
         "trial_cuts": fstats["best_cost"],
         "trial_balanced": fstats["best_balanced"],
     }
-    host = jax.device_get(fetch)
-    t_uncoarsen = time.perf_counter() - t0
+    with span("partition.fetch"):
+        host = jax.device_get(fetch)
+    return parts, parts_b, host, meta_per_level
+
+
+def partition(g, cfg: PartitionConfig) -> PartitionResult:
+    """Full multilevel partition of ``g`` into ``cfg.k`` parts.
+
+    With ``cfg.trials = T > 1``, the whole uncoarsening phase runs vmapped
+    over T seed trials on the shared hierarchy and the returned partition
+    is the device-selected best trial; ``trial_cuts`` / ``trial_balanced``
+    / ``trial_parts`` expose the full batch.  Trial ``t`` is bit-identical
+    to a ``trials=1`` run with ``trial_seeds=(seeds[t],)``.
+    """
+    with span("partition", n_max=g.n_max, m_max=g.m_max, k=cfg.k,
+              trials=cfg.trials):
+        return _partition(g, cfg)
+
+
+def _partition(g, cfg: PartitionConfig) -> PartitionResult:
+    k = cfg.k
+    seeds = _resolve_trial_seeds(cfg)
+    trials = cfg.trials
+    times: dict = {}
+    t0 = time.perf_counter()
+    with span("partition.coarsen", times, "coarsen_s"):
+        levels = co.multilevel_coarsen(
+            g,
+            coarse_target=cfg.coarse_target,
+            max_levels=cfg.max_levels,
+            stall_ratio=cfg.stall_ratio,
+            seed=cfg.seed,
+            mode=cfg.coarsen_mode,
+            bucket_ratio=cfg.bucket_ratio,
+            bucket_safety=cfg.bucket_safety,
+            bucket_align=cfg.bucket_align,
+        )
+
+    with span("partition.initial"):
+        gc = levels[-1].graph
+        parts_b = initial.initial_partition_batch(gc, k, seeds,
+                                                  method=cfg.init_method)
+
+    with span("partition.uncoarsen", times, "uncoarsen_s"):
+        parts, parts_b, host, meta_per_level = _uncoarsen(
+            g, levels, parts_b, cfg)
+    times["total_s"] = time.perf_counter() - t0
 
     level_stats = []
     for j, meta in enumerate(meta_per_level):
@@ -623,12 +654,7 @@ def partition(g, cfg: PartitionConfig) -> PartitionResult:
         imbalance=float(host["imbalance"]),
         balanced=bool(host["balanced"]),
         levels=len(levels),
-        times={
-            "coarsen_s": t_coarsen,
-            "initpart_s": t_init,
-            "uncoarsen_s": t_uncoarsen,
-            "total_s": t_coarsen + t_init + t_uncoarsen,
-        },
+        times=times,
         level_stats=level_stats,
         config=cfg,
         trials=trials,

@@ -226,22 +226,26 @@ def _refine_loop(
     def body(st: RefineState):
         balanced = jnp.max(st.conn.sizes) <= limit
         # one ConnQueries per iteration, shared by all three move kinds
-        q = cn.state_queries(g, st.conn, st.parts, k, backend)
+        with jax.named_scope("jet.queries"):
+            q = cn.state_queries(g, st.conn, st.parts, k, backend)
 
         def do_lp(_):
-            move, dest = jetlp_moves(
-                g, st.parts, k, st.lock, c, backend, variant, queries=q
-            )
+            with jax.named_scope("jet.lp"):
+                move, dest = jetlp_moves(
+                    g, st.parts, k, st.lock, c, backend, variant, queries=q
+                )
             return move, dest, move, jnp.int32(0), jnp.int32(1), jnp.int32(0)
 
         def do_rb(_):
             def weak(_):
-                return rb.jetrw_moves(g, st.parts, k, lam, backend,
-                                      conn=st.conn, queries=q)
+                with jax.named_scope("jet.rw"):
+                    return rb.jetrw_moves(g, st.parts, k, lam, backend,
+                                          conn=st.conn, queries=q)
 
             def strong(_):
-                return rb.jetrs_moves(g, st.parts, k, lam, backend,
-                                      conn=st.conn, queries=q)
+                with jax.named_scope("jet.rs"):
+                    return rb.jetrs_moves(g, st.parts, k, lam, backend,
+                                          conn=st.conn, queries=q)
 
             move, dest = jax.lax.cond(st.weak_count < b_max, weak, strong,
                                       None)
@@ -256,11 +260,13 @@ def _refine_loop(
 
         # Alg 4.4 delta update; `rebuild_every` is the full-rebuild hatch.
         def incr(_):
-            return cn.apply_moves(g, st.conn, st.parts, move, dest, k,
-                                  backend)
+            with jax.named_scope("jet.apply"):
+                return cn.apply_moves(g, st.conn, st.parts, move, dest, k,
+                                      backend)
 
         def full(_):
-            return cn.rebuild_state(g, st.conn, parts2, k, backend)
+            with jax.named_scope("jet.apply"):
+                return cn.rebuild_state(g, st.conn, parts2, k, backend)
 
         if rebuild_every == 1:
             conn2 = full(None)

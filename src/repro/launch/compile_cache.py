@@ -10,8 +10,9 @@ partitioner's per-rung programs are small but numerous, exactly the
 population the default min-compile-time filter would skip.
 
 :class:`CompileCacheStats` counts JAX's monitoring events: backend
-compiles (persistent-cache hits included) with their seconds, and the
-cache's hits and misses, for the process and for each thread.
+compiles (persistent-cache hits included) with their seconds, tracing
+and lowering seconds, and the cache's hits and misses, for the process
+and for each thread.
 """
 from __future__ import annotations
 
@@ -23,7 +24,13 @@ import jax
 
 CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax-compile-cache"
 
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+# JAX's duration events, summed under these keys
+_DURATION_KEYS = {
+    _TRACE_EVENT: "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "compile_s",
+}
 
 
 class CompileCacheStats:
@@ -31,7 +38,13 @@ class CompileCacheStats:
 
     ``compiles`` counts every backend compile request and ``compile_s``
     sums their seconds; a persistent-cache hit is one of them, served from
-    disk instead of XLA.  ``cache_hits`` / ``cache_misses`` are emitted
+    disk instead of XLA.  ``trace_s`` sums the seconds spent tracing
+    functions to jaxprs and ``lower_s`` those lowering jaxprs to MLIR, the
+    two steps before the backend that the persistent cache cannot skip.
+    An inner ``jit`` traced while an outer one is being traced reports a
+    trace of its own inside the outer one; only the outermost counts, so
+    ``trace_s`` is wall time (JAX marks each trace's start with a scalar
+    event, :meth:`on_scalar`).  ``cache_hits`` / ``cache_misses`` are emitted
     only while the persistent cache is enabled.  JAX reports each event
     in the thread that compiled, so every thread also keeps its own
     tally (:meth:`snapshot` with ``this_thread=True``).
@@ -40,6 +53,7 @@ class CompileCacheStats:
     def __init__(self):
         self.counts: dict[str, float] = {}
         self._per_thread: dict[int, dict[str, float]] = {}
+        self._trace_depth: dict[int, int] = {}
         self._lock = threading.Lock()
 
     def _add(self, key: str, value: float) -> None:
@@ -52,10 +66,24 @@ class CompileCacheStats:
         if name.startswith("/jax/compilation_cache/"):
             self._add(name.rsplit("/", 1)[-1], 1)
 
+    def on_scalar(self, name: str, value: float, **kw) -> None:
+        if name == _TRACE_EVENT:  # a trace starts
+            me = threading.get_ident()
+            self._trace_depth[me] = self._trace_depth.get(me, 0) + 1
+
     def on_duration(self, name: str, secs: float, **kw) -> None:
-        if name == _COMPILE_EVENT:
+        key = _DURATION_KEYS.get(name)
+        if key is None:
+            return
+        if name == _TRACE_EVENT:
+            me = threading.get_ident()
+            depth = max(self._trace_depth.get(me, 0) - 1, 0)
+            self._trace_depth[me] = depth
+            if depth:  # nested in a trace that is still running
+                return
+        if key == "compile_s":
             self._add("compiles", 1)
-            self._add("compile_s", secs)
+        self._add(key, secs)
 
     def snapshot(self, this_thread: bool = False) -> dict[str, float]:
         with self._lock:
@@ -80,6 +108,7 @@ def cache_stats() -> CompileCacheStats:
         jax.monitoring.register_event_listener(_CACHE_STATS)
         jax.monitoring.register_event_duration_secs_listener(
             _CACHE_STATS.on_duration)
+        jax.monitoring.register_scalar_listener(_CACHE_STATS.on_scalar)
     return _CACHE_STATS
 
 

@@ -43,6 +43,7 @@ from repro.core.partition import (
     PartitionConfig, PartitionResult, partition_fleet_stacked,
     uncoarsen_level_fleet,
 )
+from repro.core.spans import span
 from repro.launch.compile_cache import CompileCacheStats, cache_stats
 
 
@@ -76,6 +77,7 @@ class _Request:
     cfg_key: tuple       # grouping key: (k, trials, seed, trial_seeds)
     future: asyncio.Future
     t_enqueue: float
+    rid: int             # the server's request sequence number
 
 
 def _resolve_cfg(base: PartitionConfig, k, trials, seed, trial_seeds):
@@ -129,6 +131,7 @@ class PartitionServer:
             "buckets": 0, "filler_lanes": 0,
             "occupancy_hist": {},      # real lanes per dispatched bucket
             "latency_s": deque(maxlen=8192),  # enqueue -> response
+            "queue_wait_s": deque(maxlen=8192),  # enqueue -> dispatch
         }
         self.dispatch_log: deque = deque(maxlen=2048)  # signature records
         self.warmup_log: deque = deque(maxlen=2048)    # same, AOT grid
@@ -200,7 +203,8 @@ class PartitionServer:
                        cfg_key=(cfg.k, cfg.trials, cfg.seed,
                                 cfg.trial_seeds),
                        future=asyncio.get_running_loop().create_future(),
-                       t_enqueue=time.perf_counter())
+                       t_enqueue=time.perf_counter(),
+                       rid=self.stats["requests"])
         await self._queue.put(req)
         return await req.future
 
@@ -243,8 +247,9 @@ class PartitionServer:
     async def _dispatch_group(self, cfg: PartitionConfig,
                               reqs: list[_Request]) -> None:
         try:
-            results, log = await asyncio.get_running_loop().run_in_executor(
-                self._pool, self._dispatch, cfg, reqs)
+            results, log, t_start = await (
+                asyncio.get_running_loop().run_in_executor(
+                    self._pool, self._dispatch, cfg, reqs))
         except Exception as e:  # noqa: BLE001 — routed to callers
             for r in reqs:
                 if not r.future.done():
@@ -267,20 +272,26 @@ class PartitionServer:
                     continue
                 self.stats["responses"] += 1
                 self.stats["latency_s"].append(t_done - r.t_enqueue)
+                self.stats["queue_wait_s"].append(t_start - r.t_enqueue)
                 r.future.set_result(res)
 
     def _dispatch(self, cfg: PartitionConfig, reqs: list[_Request]):
         """One coalesced fleet run (worker thread): assemble -> stacked
         fleet -> route.  Request order within the group is preserved.
-        Returns (results, log record); the caller applies the record to
-        the server's stats so this thread never touches shared state."""
-        asm = gr.BucketAssembler(self.schedule, lanes=self.cfg.lanes)
-        for i, r in enumerate(reqs):
-            asm.add(i, r.graph)
-        buckets = asm.flush()
-        fres = partition_fleet_stacked(buckets, cfg, self.schedule)
+        Returns (results, log record, the dispatch's start time); the
+        caller applies them to the server's stats so this thread never
+        touches shared state."""
+        t_start = time.perf_counter()
+        ids = " ".join(str(r.rid) for r in reqs)  # "," splits trace args
+        with span("serve.assemble", requests=ids):
+            asm = gr.BucketAssembler(self.schedule, lanes=self.cfg.lanes)
+            for i, r in enumerate(reqs):
+                asm.add(i, r.graph)
+            buckets = asm.flush()
+        with span("serve.dispatch", requests=ids):
+            fres = partition_fleet_stacked(buckets, cfg, self.schedule)
         log = self._log_record(cfg, buckets, fres, len(reqs))
-        return [fres.results[i] for i in range(len(reqs))], log
+        return [fres.results[i] for i in range(len(reqs))], log, t_start
 
     @staticmethod
     def _log_record(cfg, buckets, fres, nreq) -> dict:
@@ -395,6 +406,7 @@ class PartitionServer:
         import numpy as np
 
         lat = sorted(self.stats["latency_s"])
+        wait = sorted(self.stats["queue_wait_s"])
         occ = self.stats["occupancy_hist"]
         occ_total = sum(occ.values())
         return {
@@ -413,6 +425,10 @@ class PartitionServer:
             else 0.0,
             "p95_latency_ms": 1e3 * float(np.percentile(lat, 95)) if lat
             else 0.0,
+            "p50_queue_wait_ms": 1e3 * float(np.percentile(wait, 50))
+            if wait else 0.0,
+            "p90_queue_wait_ms": 1e3 * float(np.percentile(wait, 90))
+            if wait else 0.0,
             "uncoarsen_executables": uncoarsen_level_fleet._cache_size(),
             "compile_cache": cache_stats().snapshot(),
         }

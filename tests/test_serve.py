@@ -132,3 +132,43 @@ def test_cli_fleet_rejects_duplicate_member_names(capsys):
 
     assert _parse_fleet_spec("grid:8:0", 16, 0) != \
         _parse_fleet_spec("grid:8:1", 16, 0)
+
+
+def test_queue_wait_is_counted_and_dispatches_are_spanned(tmp_path):
+    """Each response records its wait from enqueue to the start of its
+    dispatch, never longer than its latency; the worker's assembly and
+    dispatch are host spans carrying the request ids."""
+    import jax
+    from jax.profiler import ProfileData
+
+    server = _server()
+    gs = [gen.grid2d(6, 6), gen.grid2d(6, 5), gen.grid2d(4, 4)]
+
+    async def run():
+        async with server:
+            return await asyncio.gather(
+                *(server.submit(g, k=2) for g in gs))
+
+    asyncio.run(run())  # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        asyncio.run(run())
+    waits = list(server.stats["queue_wait_s"])
+    lats = list(server.stats["latency_s"])
+    assert len(waits) == len(lats) == 2 * len(gs)
+    assert all(0 <= w <= lat for w, lat in zip(waits, lats))
+    m = server.metrics()
+    assert 0 <= m["p50_queue_wait_ms"] <= m["p90_queue_wait_ms"]
+    assert m["p90_queue_wait_ms"] <= 1e3 * max(waits)
+
+    path = sorted(tmp_path.glob("**/*.xplane.pb"))[-1]
+    spans = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("serve."):
+                    spans.setdefault(ev.name, []).append(dict(ev.stats))
+    assert set(spans) == {"serve.assemble", "serve.dispatch"}
+    ids = {int(i) for args in spans["serve.dispatch"]
+           for i in str(args["requests"]).split()}
+    assert ids == {4, 5, 6}  # the second burst's requests
+    assert len(spans["serve.assemble"]) == len(spans["serve.dispatch"])
