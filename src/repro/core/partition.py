@@ -103,6 +103,10 @@ def _uncoarsen_trials(
     None on the single-graph path; on the fleet path it is the lane's
     refine-active flag, threaded into the loop condition so frozen lanes
     pass their (identity-projected) partition through untouched.
+
+    A single-graph batch of one trial runs unbatched, so the loop's
+    ``lax.cond``s stay conds and each iteration computes only the move kind
+    it takes; under the vmap they become selects (DESIGN.md §9).
     """
 
     def one_trial(parts_coarse):
@@ -119,6 +123,9 @@ def _uncoarsen_trials(
             rebuild_every=rebuild_every, active=active,
         )
 
+    if active is None and parts_batch.shape[0] == 1:
+        return jax.tree_util.tree_map(lambda x: x[None],
+                                      one_trial(parts_batch[0]))
     return jax.vmap(one_trial)(parts_batch)
 
 
@@ -146,7 +153,8 @@ def uncoarsen_level(
     rebuild_every: int,
     max_degree: int | None = None,
 ):
-    """One uncoarsening level, fused and vmapped over the trial axis.
+    """One uncoarsening level, fused and vmapped over the trial axis
+    (run unbatched at T=1, see :func:`_uncoarsen_trials`).
 
     project -> ghost-mask -> ConnState build -> Jet refinement loop as a
     single XLA program.  ``parts_batch`` is (T, nc_max) coarse parts (pass

@@ -3,7 +3,10 @@
 Everything here is one jittable ``lax.while_loop`` per level: the paper's
 bulk-synchronous design maps 1:1 onto XLA.  The three iteration kinds
 (Jetlp / weak rebalance / strong rebalance) are ``lax.cond`` branches chosen
-by the balance state, exactly as Alg 4.1 alternates them.
+by the balance state, exactly as Alg 4.1 alternates them.  They stay real
+branches only unbatched: under a ``vmap`` that batches the predicates (T > 1
+trials, fleet lanes) each ``cond`` lowers to a select, and every iteration
+computes all three move kinds (DESIGN.md §9).
 
 Stateful incremental refinement (DESIGN.md §3): a :class:`~repro.core.
 connectivity.ConnState` — connectivity structure, part sizes, and cutsize —
@@ -128,6 +131,7 @@ class RefineState(NamedTuple):
     it: jnp.ndarray              # int32 total iterations
     lp_iters: jnp.ndarray        # int32 (stats)
     rb_iters: jnp.ndarray        # int32 (stats)
+    rs_iters: jnp.ndarray        # int32 (stats) strong rebalances
 
 
 def jet_refine(
@@ -211,6 +215,7 @@ def _refine_loop(
         it=jnp.int32(0),
         lp_iters=jnp.int32(0),
         rb_iters=jnp.int32(0),
+        rs_iters=jnp.int32(0),
     )
 
     def cond(st: RefineState):
@@ -234,26 +239,42 @@ def _refine_loop(
                 move, dest = jetlp_moves(
                     g, st.parts, k, st.lock, c, backend, variant, queries=q
                 )
-            return move, dest, move, jnp.int32(0), jnp.int32(1), jnp.int32(0)
+            return (move, dest, move, jnp.int32(0), jnp.int32(1),
+                    jnp.int32(0), jnp.int32(0))
 
         def do_rb(_):
+            # The rebalance kernels gather from k-entry tables (sizes, caps,
+            # per-part offsets).  Unbatched, XLA's TPU compiler expands each
+            # such gather into a k-way compare/select chain, which at k=64
+            # triples the level program's compile time; run them as a batch
+            # of one, as they run under the trial vmap.
+            def batch_of_one(kernel):
+                def one(parts, conn, queries):
+                    return kernel(g, parts, k, lam, backend, conn=conn,
+                                  queries=queries)
+
+                args = jax.tree_util.tree_map(lambda x: x[None],
+                                              (st.parts, st.conn, q))
+                return jax.tree_util.tree_map(lambda x: x[0],
+                                              jax.vmap(one)(*args))
+
             def weak(_):
                 with jax.named_scope("jet.rw"):
-                    return rb.jetrw_moves(g, st.parts, k, lam, backend,
-                                          conn=st.conn, queries=q)
+                    move, dest = batch_of_one(rb.jetrw_moves)
+                return move, dest, jnp.int32(0)
 
             def strong(_):
                 with jax.named_scope("jet.rs"):
-                    return rb.jetrs_moves(g, st.parts, k, lam, backend,
-                                          conn=st.conn, queries=q)
+                    move, dest = batch_of_one(rb.jetrs_moves)
+                return move, dest, jnp.int32(1)
 
-            move, dest = jax.lax.cond(st.weak_count < b_max, weak, strong,
-                                      None)
+            move, dest, drs = jax.lax.cond(st.weak_count < b_max, weak,
+                                           strong, None)
             # rebalancing does not touch lock state (paper §4.1.3)
             return (move, dest, st.lock, st.weak_count + 1, jnp.int32(0),
-                    jnp.int32(1))
+                    jnp.int32(1), drs)
 
-        move, dest, lock2, weak2, dlp, drb = jax.lax.cond(
+        move, dest, lock2, weak2, dlp, drb, drs = jax.lax.cond(
             balanced, do_lp, do_rb, None
         )
         parts2 = jnp.where(move, dest, st.parts)
@@ -305,6 +326,7 @@ def _refine_loop(
             it=st.it + 1,
             lp_iters=st.lp_iters + dlp,
             rb_iters=st.rb_iters + drb,
+            rs_iters=st.rs_iters + drs,
         )
 
     st = jax.lax.while_loop(cond, body, st)
@@ -312,6 +334,7 @@ def _refine_loop(
         "iterations": st.it,
         "lp_iters": st.lp_iters,
         "rb_iters": st.rb_iters,
+        "rs_iters": st.rs_iters,
         "best_cost": st.best_cost,
         "best_maxsize": st.best_maxsize,
         "best_balanced": st.best_balanced,

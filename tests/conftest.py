@@ -11,6 +11,12 @@
   (``repro.launch.compile_cache``).  Inside a test it goes to a per-worker
   temp directory, and the cache settings are restored after the test, so
   no test writes into the checkout or changes what later tests compile.
+* XLA's CPU backend maps each compiled executable's code in regions of its
+  own, and a worker that has compiled a few thousand programs reaches the
+  kernel's limit on mapped regions (``vm.max_map_count``, 65,530 by
+  default): the next compile fails in LLVM ("Cannot allocate memory") and
+  the worker dies with a segfault.  Once a test leaves the process past
+  60% of the limit, JAX's caches are dropped, which unmaps them.
 """
 from __future__ import annotations
 
@@ -43,6 +49,26 @@ def _scoped_compile_cache(monkeypatch, tmp_path_factory):
         for o, v in before.items():
             jax.config.update(o, v)
         cc.reset_cache()
+
+def _map_counts() -> tuple[int, int] | None:
+    """(mapped regions of this process, the kernel's limit), or None where
+    ``/proc`` does not say."""
+    try:
+        with open("/proc/self/maps", "rb") as f:
+            regions = sum(1 for _ in f)
+        with open("/proc/sys/vm/max_map_count") as f:
+            return regions, int(f.read())
+    except (OSError, ValueError):
+        return None
+
+
+@pytest.fixture(autouse=True)
+def _bounded_code_mappings():
+    yield
+    counts = _map_counts()
+    if counts is not None and counts[0] > 0.6 * counts[1]:
+        jax.clear_caches()
+
 
 _BACKENDS = ("dense", "sorted", "ell")
 

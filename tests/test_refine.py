@@ -104,6 +104,27 @@ def test_jet_refine_from_unbalanced_start():
     assert int(stats["rb_iters"]) >= 1
 
 
+@pytest.mark.parametrize("b_max", [0, 1, 1000])
+def test_rs_iters_counts_strong_rebalances(b_max):
+    """``rs_iters`` counts the strong rebalances among ``rb_iters``: all of
+    them at ``b_max=0``, some at ``b_max=1`` and none when the weak budget
+    is never spent."""
+    g = gen.grid2d(24, 24)
+    k = 6
+    parts0 = jnp.where(g.vertex_mask(), 0, k).astype(jnp.int32)
+    _, stats = refine.jet_refine(g, parts0, k, lam=0.05, b_max=b_max,
+                                 max_iter=60)
+    rb_iters, rs_iters = int(stats["rb_iters"]), int(stats["rs_iters"])
+    assert rb_iters >= 1
+    assert int(stats["iterations"]) == int(stats["lp_iters"]) + rb_iters
+    if b_max == 0:
+        assert rs_iters == rb_iters
+    elif b_max == 1:
+        assert 0 < rs_iters < rb_iters
+    else:
+        assert rs_iters == 0
+
+
 @pytest.mark.parametrize("variant", list(refine.VARIANTS))
 def test_refine_variants_run(variant):
     g = gen.grid2d(12, 12)

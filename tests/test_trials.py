@@ -7,8 +7,10 @@ on every backend.  Plus: device-side best-trial ordering, the fused
 ``uncoarsen_level`` against the legacy unfused sequence, and the
 mask-aware voronoi seed guard.
 """
+import re
 from dataclasses import replace
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -97,6 +99,49 @@ def test_uncoarsen_level_matches_unfused(backend):
         np.testing.assert_array_equal(np.asarray(fused_b[t]), np.asarray(ref))
         for kk in ref_stats:
             assert int(stats_b[kk][t]) == int(ref_stats[kk]), (kk, t)
+
+
+@pytest.mark.parametrize("backend", ["dense", "ell"])
+def test_uncoarsen_level_conds_only_unbatched(backend):
+    """At T=1 the level program keeps Alg 4.1's two ``lax.cond``s (the
+    balance switch and weak-or-strong), so an iteration runs only the move
+    kind it takes; at T=2 the batched predicates turn both into selects."""
+    g = gen.grid2d(16, 16)
+    k = 4
+    levels = co.multilevel_coarsen(g, coarse_target=64, seed=0)
+    fine, coarse = levels[-2], levels[-1]
+    max_degree = (int(jnp.max(fine.graph.degrees())) if backend == "ell"
+                  else None)
+    kw = dict(k=k, lam=0.03, c=0.75, backend=backend, patience=4,
+              max_iter=40, b_max=2, variant="full", rebuild_every=0,
+              max_degree=max_degree)
+    conds = {}
+    for T in (1, 2):
+        parts_b = initial.initial_partition_batch(coarse.graph, k,
+                                                  tuple(range(T)))
+        jaxpr = jax.make_jaxpr(
+            lambda f, cm, pb: uncoarsen_level(f, cm, pb, 0.999, **kw)
+        )(fine.graph, fine.cmap, parts_b)
+        conds[T] = len(re.findall(r"\bcond\[", str(jaxpr)))
+    assert conds == {1: 2, 2: 0}, conds
+
+
+@pytest.mark.parametrize("backend", ["dense", "sorted", "ell"])
+@pytest.mark.parametrize("k", [2, 33])
+def test_single_trial_level_stats_match_batched(backend, k):
+    """The unbatched T=1 path reports, level by level, the same stats
+    (``rs_iters`` included) as trial 0 of a T=2 batch with the same seeds."""
+    g = gen.grid2d(12, 12)
+    cfg = _cfg(backend, k, trials=2, trial_seeds=(4, 9))
+    batched = partition(g, cfg)
+    single = partition(g, replace(cfg, trials=1, trial_seeds=(4,)))
+    assert len(single.level_stats) == len(batched.level_stats)
+    for lv1, lv2 in zip(single.level_stats, batched.level_stats):
+        assert "rs_iters" in lv1
+        assert 0 <= lv1["rs_iters"] <= lv1["rb_iters"]
+        for key, val in lv1.items():
+            got = lv2[key][0] if isinstance(lv2[key], list) else lv2[key]
+            assert got == val, (backend, k, lv1["level"], key)
 
 
 def test_voronoi_seeds_mask_aware():
