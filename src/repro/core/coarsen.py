@@ -14,8 +14,11 @@ Two coarsening paths share the matching/contraction kernels (DESIGN.md §8):
   The driver re-buckets the result into a precomputed geometric
   :func:`shape_schedule` of (n_max, m_max) capacities, so kernels compile
   once per capacity rung instead of once per exact size.  The only host
-  syncs left are one 3-int32 stat fetch per level (termination check +
-  capacity selection).
+  syncs left are one 6-int32 stat fetch per level: the coarse graph's
+  size (termination check + capacity selection) and the level's matching
+  counters (:data:`COUNTERS`).  The level's device phases carry the scopes
+  ``coarsen.hem``, ``coarsen.twohop``, ``coarsen.contract`` and
+  ``coarsen.csr``.
 * **host** (legacy): :func:`coarsen_once` repacks the coarse graph into
   tight arrays on host via numpy — kept as the equivalence/bench baseline.
 """
@@ -227,10 +230,18 @@ def contract_edges(g: Graph, cmap: jnp.ndarray):
     return cu_run, cv_run, w_run, run_valid, n_runs, vwgt_c
 
 
+COUNTERS = ("hem_unmatched", "twohop", "twohop_pairs")
+"""Counters of one device coarsening level, in :func:`coarsen_level`'s
+order: real vertices HEM left unmatched, whether the two-hop pass ran
+(0 or 1), and the pairs it added."""
+
+
 class CoarsenLevel(NamedTuple):
     graph: Graph
     cmap: jnp.ndarray  # fine vertex -> coarse vertex of the NEXT level
-    stats: dict | None = None  # host ints: n, m, max_degree, n_max, m_max
+    # host ints: n, m, max_degree, n_max, m_max, and on the device path the
+    # COUNTERS of the matching run on this graph, where one ran
+    stats: dict | None = None
 
 
 def _round_up(x: int, mult: int = 8) -> int:
@@ -318,23 +329,38 @@ def coarsen_level(
 
     ``seed``/``twohop_threshold``/``mm_max_degree`` are traced, so changing
     them never recompiles; only the capacity bucket (array shapes) does.
+
+    Returns ``(gc, cmap, counts)``: ``counts`` is an int32 (3,) array of
+    the level's :data:`COUNTERS`, computed on the device.
     """
-    match = heavy_edge_matching(g, rounds=hem_rounds, seed=seed)
-    unmatched = jnp.sum(((match < 0) & g.vertex_mask()).astype(jnp.int32))
-    frac = unmatched.astype(jnp.float32) / jnp.maximum(g.n, 1).astype(jnp.float32)
-    match = jax.lax.cond(
-        frac > twohop_threshold,
-        lambda m: twohop_matching(g, m, mm_max_degree, seed),
-        lambda m: m,
-        match,
-    )
-    cmap, nc = coarse_map(g, match)
-    cu_run, cv_run, w_run, run_valid, n_runs, vwgt_c = contract_edges(g, cmap)
-    gc = csr_from_edge_runs(
-        cu_run, cv_run, w_run, run_valid, n_runs, vwgt_c, nc,
-        n_max=g.n_max, m_max=g.m_max,
-    )
-    return gc, cmap
+    vmask = g.vertex_mask()
+    with jax.named_scope("coarsen.hem"):
+        match = heavy_edge_matching(g, rounds=hem_rounds, seed=seed)
+        unmatched = jnp.sum(((match < 0) & vmask).astype(jnp.int32))
+        frac = (unmatched.astype(jnp.float32)
+                / jnp.maximum(g.n, 1).astype(jnp.float32))
+        twohop = frac > twohop_threshold
+    with jax.named_scope("coarsen.twohop"):
+        match = jax.lax.cond(
+            twohop,
+            lambda m: twohop_matching(g, m, mm_max_degree, seed),
+            lambda m: m,
+            match,
+        )
+        # two-hop pairs only vertices HEM left unmatched, two at a time
+        left = jnp.sum(((match < 0) & vmask).astype(jnp.int32))
+    with jax.named_scope("coarsen.contract"):
+        cmap, nc = coarse_map(g, match)
+        cu_run, cv_run, w_run, run_valid, n_runs, vwgt_c = contract_edges(
+            g, cmap)
+    with jax.named_scope("coarsen.csr"):
+        gc = csr_from_edge_runs(
+            cu_run, cv_run, w_run, run_valid, n_runs, vwgt_c, nc,
+            n_max=g.n_max, m_max=g.m_max,
+        )
+    counts = jnp.stack([unmatched, twohop.astype(jnp.int32),
+                        (unmatched - left) // 2])
+    return gc, cmap, counts
 
 
 @jax.jit
@@ -345,16 +371,32 @@ def _level_stats_dev(g: Graph) -> jnp.ndarray:
     ).astype(jnp.int32)
 
 
+@jax.jit
+def _level_stats_counts_dev(g: Graph, counts: jnp.ndarray) -> jnp.ndarray:
+    """:func:`_level_stats_dev` of the coarse graph followed by the
+    counters of the level that made it, for the same ONE transfer."""
+    return jnp.concatenate([_level_stats_dev(g), counts])
+
+
 @partial(jax.jit, static_argnames=("n_max", "m_max"))
 def _rebucket(g: Graph, n_max: int, m_max: int) -> Graph:
     return g.with_capacity(n_max, m_max)
 
 
-def _fetch_stats(g: Graph, level: int = 0) -> dict:
+def _fetch_stats(g: Graph, level: int = 0,
+                 counts: jnp.ndarray | None = None) -> tuple[dict, dict]:
+    """Host stats of ``g``, and the :data:`COUNTERS` of the level that
+    made it where ``counts`` is given (else empty), in one transfer."""
     with span("coarsen.fetch", level=level):
-        n, m, max_deg = (int(x) for x in np.asarray(_level_stats_dev(g)))
-    return {"n": n, "m": m, "max_degree": max_deg,
-            "n_max": g.n_max, "m_max": g.m_max}
+        if counts is None:
+            vals = [int(x) for x in np.asarray(_level_stats_dev(g))]
+        else:
+            vals = [int(x) for x in
+                    np.asarray(_level_stats_counts_dev(g, counts))]
+    n, m, max_deg = vals[:3]
+    return ({"n": n, "m": m, "max_degree": max_deg,
+             "n_max": g.n_max, "m_max": g.m_max},
+            dict(zip(COUNTERS, vals[3:])))
 
 
 def shape_schedule(
@@ -459,7 +501,7 @@ def _coarsen_step_fleet(gb: Graph, seed, twohop_threshold, mm_max_degree):
     """
 
     def one(g):
-        gc, cmap = coarsen_level(g, seed, twohop_threshold, mm_max_degree)
+        gc, cmap, _ = coarsen_level(g, seed, twohop_threshold, mm_max_degree)
         return gc, cmap, _level_stats_dev(gc)
 
     return jax.vmap(one)(gb)
@@ -580,7 +622,9 @@ def multilevel_coarsen(
     The last entry's cmap is None (coarsest graph).  Every level carries
     host ``stats`` (n, m, max_degree, capacities) captured in one per-level
     transfer, so downstream consumers (ELL backend, ConnState build) never
-    re-sync.
+    re-sync.  On the device path a level's stats also hold the
+    :data:`COUNTERS` of the matching run on its graph: every level but the
+    coarsest, and the coarsest too where its own attempt stalled.
 
     ``mode="device"`` (default) runs each level via :func:`coarsen_level`
     and re-buckets results along ``schedule`` (a :func:`shape_schedule`
@@ -591,7 +635,7 @@ def multilevel_coarsen(
     if mode not in ("device", "host"):
         raise ValueError(f"unknown coarsen mode {mode!r}")
     cur = g
-    stats0 = _fetch_stats(cur)
+    stats0, _ = _fetch_stats(cur)
     if mode == "device":
         if schedule is None:
             schedule = shape_schedule(
@@ -610,24 +654,26 @@ def multilevel_coarsen(
                       "m_max": schedule[0][1]}
 
     def step(fine, lvl):
-        """One level + its stats; per-level host syncs live here."""
+        """One level, its stats and the fine level's counters; per-level
+        host syncs live here."""
         if mode == "host":
             gc, cmap = coarsen_once(
                 fine, twohop_threshold=twohop_threshold,
                 mm_max_degree=mm_max_degree, seed=seed + lvl,
             )
-            return gc, cmap, _fetch_stats(gc, lvl + 1)
-        gc, cmap = coarsen_level(
+            return (gc, cmap) + _fetch_stats(gc, lvl + 1)
+        gc, cmap, counts = coarsen_level(
             fine, seed=seed + lvl, twohop_threshold=twohop_threshold,
             mm_max_degree=mm_max_degree,
         )
-        # The ONLY device-path host sync: 3 int32 (termination + capacity).
-        st = _fetch_stats(gc, lvl + 1)
+        # The ONLY device-path host sync: 6 int32 (termination + capacity,
+        # and the counters).
+        st, counted = _fetch_stats(gc, lvl + 1, counts)
         cap = select_capacity(schedule, st["n"], st["m"])
         if cap != (gc.n_max, gc.m_max):
             gc = _rebucket(gc, *cap)
             st = {**st, "n_max": cap[0], "m_max": cap[1]}
-        return gc, cmap, st
+        return gc, cmap, st, counted
 
     levels: list[CoarsenLevel] = []
     stats = stats0
@@ -636,7 +682,8 @@ def multilevel_coarsen(
             break
         with span("coarsen.level", level=lvl, n_max=cur.n_max,
                   m_max=cur.m_max):
-            gc, cmap, stats_c = step(cur, lvl)
+            gc, cmap, stats_c, counted = step(cur, lvl)
+        stats = stats | counted
         if stats_c["n"] > stall_ratio * stats["n"]:  # stalled
             break
         levels.append(CoarsenLevel(graph=cur, cmap=cmap, stats=stats))

@@ -563,8 +563,7 @@ def _uncoarsen(g, levels, parts_b, cfg: PartitionConfig):
             )
         stats_per_level.append(stats)
         meta = (
-            {kk: lv_stats[kk] for kk in ("n", "m", "n_max", "m_max",
-                                         "max_degree")}
+            dict(lv_stats)  # sizes, and the level's coarsening counters
             if lv_stats is not None
             else {"n": int(gi.n), "m": int(gi.m),
                   "n_max": gi.n_max, "m_max": gi.m_max}

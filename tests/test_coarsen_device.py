@@ -120,3 +120,45 @@ def test_csr_from_edge_runs_matches_contract():
     for f in ("esrc", "adjncy", "adjwgt"):
         assert np.array_equal(np.asarray(getattr(gc_dev, f))[:m],
                               np.asarray(getattr(gc_host, f))[:m]), f
+
+
+@pytest.mark.parametrize("name, ran", [("rmat_12", 1), ("grid_64x32", 0)])
+def test_level_counters_match_a_recount(name, ran):
+    """``coarsen_level``'s counters against a numpy recount from HEM's own
+    ``match`` and the level's ``cmap``: a power-law level takes the
+    two-hop branch, a mesh level does not."""
+    g = gen.suite_graph(name)
+    gc, cmap, counts = coarsen.coarsen_level(g, seed=3)
+    hem_unmatched, twohop, pairs = (int(x) for x in np.asarray(counts))
+    n = int(g.n)
+    match = np.asarray(coarsen.heavy_edge_matching(g, seed=3))[:n]
+    left = int(np.count_nonzero(match < 0))
+    assert hem_unmatched == left
+    assert twohop == int(np.float32(left) / np.float32(n)
+                         > np.float32(0.25)) == ran
+    members = np.bincount(np.asarray(cmap)[:n])
+    assert members.shape[0] == int(gc.n) and set(members) <= {1, 2}
+    hem_pairs = (n - left) // 2
+    assert pairs == int(np.count_nonzero(members == 2)) - hem_pairs
+    assert (pairs > 0) == bool(ran)
+    # the counted level is the host path's level, bit for bit
+    gc_host, cmap_host = coarsen.coarsen_once(g, seed=3)
+    assert np.array_equal(np.asarray(cmap)[:n], np.asarray(cmap_host)[:n])
+    assert int(gc.m) == int(gc_host.m)
+
+
+def test_counted_partition_is_the_host_paths_on_a_power_law_graph():
+    """Labels and cut with the counters (device path) are bit-identical to
+    the host path's, which counts nothing; every level but the coarsest
+    carries its counters into ``level_stats``."""
+    g = gen.suite_graph("rmat_12")
+    res = {mode: partition(g, PartitionConfig(
+        k=8, coarse_target=256, max_iter=60, patience=6, coarsen_mode=mode))
+        for mode in ("device", "host")}
+    assert res["device"].cut == res["host"].cut
+    assert np.array_equal(np.asarray(res["device"].parts),
+                          np.asarray(res["host"].parts))
+    dev = res["device"].level_stats  # coarsest first
+    assert all(set(coarsen.COUNTERS) <= set(ls) for ls in dev[1:])
+    assert all(ls["twohop"] == 1 for ls in dev[1:])
+    assert not any("twohop" in ls for ls in res["host"].level_stats)
