@@ -20,11 +20,15 @@ Both backends answer the queries refinement needs (paper §4.3):
 Stateful interface (DESIGN.md §3): :class:`ConnState` packages the backend
 structure together with delta-maintained part sizes and the current cutsize.
 It is built once per level (:func:`build_state`), threaded through the
-refinement ``lax.while_loop``, advanced after each move list with
-Alg 4.4-style scatter-add deltas (:func:`apply_moves`), and refreshed from
-scratch only on the ``rebuild_every`` escape hatch (:func:`rebuild_state`).
-Incremental and rebuilt state agree bit-exactly (integer arithmetic only);
-tests/test_conn_state.py asserts this.
+refinement ``lax.while_loop`` and advanced after each move list
+(:func:`apply_moves`): the dense matrix is re-derived from the post-move
+parts by the one scatter-add of M scalars that builds it, the sorted and
+ELL structures are rewritten where a neighbor moved, the sizes advance by
+a delta and the cut by one edge pass.  The ``rebuild_every`` escape hatch
+(:func:`rebuild_state`) refreshes everything from scratch; for the dense
+matrix that is the same arithmetic.  Advanced and rebuilt state agree
+bit-exactly (integer arithmetic only); tests/test_conn_state.py asserts
+this.
 
 Batch polymorphism (DESIGN.md §§9-10): every function here is a pure jitted
 function of arrays — no shape-dependent Python branches on values, no host
@@ -224,36 +228,6 @@ def update_conn_matrix(mat: jnp.ndarray, g: Graph, parts_old: jnp.ndarray,
     return mat
 
 
-def update_conn_matrix_rows(mat: jnp.ndarray, g: Graph, parts_old: jnp.ndarray,
-                            move: jnp.ndarray, dest: jnp.ndarray,
-                            k: int) -> jnp.ndarray:
-    """Alg 4.4 delta as a row-ordered one-hot sweep (the hot-path variant).
-
-    Symmetry lets the update run source-side: "edges whose source moved
-    update their destination's row" == "edges whose destination moved update
-    their source's row", and source rows are CSR-contiguous.  The per-edge
-    one-hot difference over k+1 columns is dense compare/multiply-accumulate
-    (VPU-shaped, like the jet_gain kernel's k-sweep), and the CSR-segment
-    reduction is a cumsum + boundary gather — no scatter at all, ~2x
-    cheaper on CPU than the two random scatter-adds of
-    :func:`update_conn_matrix`, with bit-identical output (wraparound int32
-    arithmetic makes the prefix-sum difference exact).
-    """
-    dst_moved = move[g.adjncy]
-    w = jnp.where(dst_moved, g.adjwgt, 0)
-    p_old = parts_old[g.adjncy]
-    p_new = dest[g.adjncy]
-    cols = jnp.arange(k + 1, dtype=jnp.int32)
-    diff = w[:, None] * (
-        (p_new[:, None] == cols[None, :]).astype(jnp.int32)
-        - (p_old[:, None] == cols[None, :]).astype(jnp.int32)
-    )
-    csum = jnp.concatenate(
-        [jnp.zeros((1, k + 1), jnp.int32), jnp.cumsum(diff, axis=0)]
-    )
-    return mat + csum[g.xadj[1:]] - csum[g.xadj[:-1]]
-
-
 # ---------------------------------------------------------------------------
 # stateful interface — ConnState threaded through the refinement loop
 # ---------------------------------------------------------------------------
@@ -267,7 +241,8 @@ class ConnState(NamedTuple):
     advanced by a one-pass edge reduction over the post-move parts (the
     cheapest exact form under static shapes — see ``metrics.delta_cutsize``)
     and carried here so queries, balance checks, and best-tracking never
-    recompute it.
+    recompute it.  The dense ``mat`` advances the same way: re-derived from
+    the post-move parts by :func:`conn_matrix` (see :func:`apply_moves`).
     """
 
     sizes: jnp.ndarray          # (k,) int32 part weights
@@ -358,12 +333,17 @@ def apply_moves(
 ) -> ConnState:
     """Advance the state past one move list (paper Alg 4.4, all backends).
 
-    Structure updates are deltas: a scatter-free one-hot/cumsum row update
-    for the dense matrix (:func:`update_conn_matrix_rows`), masked
-    elementwise rewrites for the sorted / ELL structures, and a one-hot
-    delta reduction for part sizes; the cut advances by a one-pass edge
-    reduction.  Bit-exact against :func:`rebuild_state` (integer arithmetic
-    throughout).
+    The dense matrix is re-derived from the post-move parts by
+    :func:`conn_matrix`: one gather of M parts, which the cut's edge pass
+    shares, and one scatter-add of M scalars.  Under static shapes a masked
+    delta touches every edge too, and gathers ``move``, ``parts_old`` and
+    ``dest`` at every edge; on a TPU v5e M-length gathers dominate, and the
+    rebuild took about half the time of a one-hot prefix-sum delta and
+    under half that of :func:`update_conn_matrix` at the benchmark's
+    finest shapes (PERF.md §5).  The sorted / ELL structures get masked
+    elementwise rewrites, part sizes a one-hot delta reduction, and the cut
+    a one-pass edge reduction.  Bit-exact against :func:`rebuild_state`
+    (integer arithmetic throughout).
     """
     from repro.core import metrics
 
@@ -373,8 +353,7 @@ def apply_moves(
     upd = {"sizes": sizes, "cut": cut,
            "moves_applied": state.moves_applied + 1}
     if backend == "dense":
-        upd["mat"] = update_conn_matrix_rows(state.mat, g, parts_old, move,
-                                             dest, k)
+        upd["mat"] = conn_matrix(g, parts_new, k)
     elif backend == "sorted":
         hit = g.edge_mask() & move[g.adjncy]
         upd["edge_dst_part"] = jnp.where(

@@ -8,15 +8,19 @@ branches only unbatched: under a ``vmap`` that batches the predicates (T > 1
 trials, fleet lanes) each ``cond`` lowers to a select, and every iteration
 computes all three move kinds (DESIGN.md §9).
 
-Stateful incremental refinement (DESIGN.md §3): a :class:`~repro.core.
+Stateful refinement (DESIGN.md §3): a :class:`~repro.core.
 connectivity.ConnState` — connectivity structure, part sizes, and cutsize —
 is built once per level, threaded through :class:`RefineState` inside the
-loop, and advanced after every move list with Alg 4.4 delta updates.  The
-loop body therefore never rebuilds connectivity or recomputes sizes/cut
-from the parts vector on the default path; ``rebuild_every`` is the
-periodic-full-rebuild escape hatch (1 == the paper's always-rebuild
-fallback, 0 == never).  All three iteration kinds consume the same
-``ConnQueries`` computed once per iteration from the threaded state.
+loop, and advanced after every move list (Alg 4.4): part sizes by a delta,
+the cut by one edge pass, the sorted / ELL structures by rewrites where a
+neighbor moved, and the dense matrix by the one scatter-add that builds it,
+from the post-move parts (on a TPU v5e that beat every delta form measured,
+PERF.md §5).  ``rebuild_every`` is the periodic-full-rebuild escape hatch
+(1 == the paper's always-rebuild fallback, 0 == never); on the dense
+backend it rebuilds the matrix and the cut by the advance's own arithmetic
+and differs only in recounting the part sizes.
+All three iteration kinds consume the same ``ConnQueries`` computed once
+per iteration from the threaded state.
 
 Deviations from the paper are documented in DESIGN.md §6; the functional
 behaviour (filters, afterburner ordering, locking, best-partition tracking

@@ -1,6 +1,7 @@
 """Stateful incremental refinement: ConnState.apply_moves must agree
 bit-exactly with a from-scratch rebuild — connectivity structure, part
 sizes, and cutsize — across many random move lists (paper Alg 4.4)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -11,7 +12,8 @@ from repro.core.graph import build_csr_host
 from repro.data import graphs as gen
 
 
-def _weighted_graph(seed=0, n=200, n_edges=700):
+def _weighted_graph(seed=0, n=200, n_edges=700, pad=0):
+    """``pad`` > 0 adds that many padding vertices and padding edges."""
     rng = np.random.default_rng(seed)
     path = np.stack([np.arange(n - 1), np.arange(1, n)], 1)
     extra = rng.integers(0, n, (n_edges, 2))
@@ -19,7 +21,10 @@ def _weighted_graph(seed=0, n=200, n_edges=700):
     edges = edges[edges[:, 0] != edges[:, 1]]
     ew = rng.integers(1, 8, edges.shape[0])
     vw = rng.integers(1, 4, n)
-    return build_csr_host(n, edges, ew, vw)
+    if not pad:
+        return build_csr_host(n, edges, ew, vw)
+    return build_csr_host(n, edges, ew, vw, n_max=n + pad,
+                          m_max=2 * edges.shape[0] + pad)
 
 
 def _rand_parts(g, k, rng):
@@ -35,7 +40,7 @@ def _rand_moves(g, parts, k, rng, frac=0.15):
 
 def _assert_states_equal(st, ref, backend):
     np.testing.assert_array_equal(np.asarray(st.sizes), np.asarray(ref.sizes))
-    assert int(st.cut) == int(ref.cut)
+    np.testing.assert_array_equal(np.asarray(st.cut), np.asarray(ref.cut))
     if backend == "dense":
         np.testing.assert_array_equal(np.asarray(st.mat), np.asarray(ref.mat))
     elif backend == "sorted":
@@ -89,6 +94,43 @@ def test_apply_moves_matches_rebuild_ell(k):
         qb = cn.queries(g, parts, k, backend="dense")
         for a, b in zip(qa, qb):
             np.testing.assert_array_equal(np.asarray(a)[:n], np.asarray(b)[:n])
+
+
+@pytest.mark.parametrize("frac", [0.0, 0.05, 1.0])
+@pytest.mark.parametrize("k", [2, 8, 33, 64])
+def test_dense_apply_moves_equals_rebuild(k, frac):
+    """The dense advance past one move list equals a rebuild from the
+    post-move parts, bit for bit, with padding vertices and edges."""
+    g = _weighted_graph(seed=k, n=150, n_edges=500, pad=21)
+    assert g.n_max > int(g.n) and g.m_max > int(g.m)
+    rng = np.random.default_rng(1000 * k + int(100 * frac))
+    parts = _rand_parts(g, k, rng)
+    st = cn.build_state(g, parts, k, "dense")
+    move, dest = _rand_moves(g, parts, k, rng, frac)
+    out = cn.apply_moves(g, st, parts, move, dest, k, "dense")
+    ref = cn.rebuild_state(g, st, jnp.where(move, dest, parts), k, "dense")
+    _assert_states_equal(out, ref, "dense")
+    assert int(out.moves_applied) == 1
+
+
+def test_dense_apply_moves_equals_rebuild_vmapped():
+    """The same under ``vmap`` over a trial axis of 3, one move fraction
+    (0, 5% and 100%) per trial, the graph unbatched."""
+    k = 8
+    g = _weighted_graph(seed=5, n=150, n_edges=500, pad=21)
+    rng = np.random.default_rng(5)
+    parts = jnp.stack([_rand_parts(g, k, rng) for _ in range(3)])
+    md = [_rand_moves(g, parts[t], k, rng, f)
+          for t, f in enumerate((0.0, 0.05, 1.0))]
+    move = jnp.stack([m for m, _ in md])
+    dest = jnp.stack([d for _, d in md])
+    st = jax.vmap(lambda p: cn.build_state(g, p, k, "dense"))(parts)
+    out = jax.vmap(lambda s, p, m, d: cn.apply_moves(
+        g, s, p, m, d, k, "dense"))(st, parts, move, dest)
+    ref = jax.vmap(lambda s, p: cn.rebuild_state(g, s, p, k, "dense"))(
+        st, jnp.where(move, dest, parts))
+    _assert_states_equal(out, ref, "dense")
+    np.testing.assert_array_equal(np.asarray(out.moves_applied), [1, 1, 1])
 
 
 def test_delta_metrics_match_recompute():
