@@ -5,10 +5,7 @@ size-constrained-LP refinement (our implementable stand-in for the LP-based
 competitors), across k and imbalance settings, and reports the paper's
 Table 2 phase breakdown (coarsen / initial partition / uncoarsen).
 
-Also the device-resident coarsening A/B (DESIGN.md §8): phase timings for
-``coarsen_mode="host"`` (legacy numpy repack) vs ``"device"`` (one jitted
-kernel per level on the static shape schedule), and the batched-trials A/B
-(DESIGN.md §9): a sequential T-loop vs one vmapped best-of-T batch, gated
+Also the batched-trials A/B (DESIGN.md §9): a sequential T-loop vs one vmapped best-of-T batch, gated
 on per-trial cut equivalence and on the compile count (one
 ``uncoarsen_level`` executable per capacity-rung signature regardless of
 T), and the fleet A/B (DESIGN.md §10): a sequential per-graph loop vs one
@@ -33,11 +30,11 @@ import numpy as np
 
 from benchmarks.graphs_suite import SUITE, load
 from repro.core import coarsen as co
+from repro.core import graph as gr
 from repro.core import initial, metrics
 from repro.core.lp_baseline import constrained_lp_refine
 from repro.core.partition import (
     PartitionConfig, partition, partition_fleet, uncoarsen_level,
-    uncoarsen_level_fleet,
 )
 
 
@@ -61,17 +58,17 @@ def _clp_multilevel(g, k, lam, seed):
     """Same multilevel pipeline, constrained-LP refinement instead of Jet
     (both get balanced inputs at every level; the variable under test is
     the LP-vs-Jetlp cut optimization)."""
-    levels = co.multilevel_coarsen(g, coarse_target=max(1024, 8 * k),
-                                   seed=seed)
-    gc = levels[-1].graph
-    parts = initial.initial_partition(gc, k, seed=seed)
+    levels = co.multilevel_coarsen(gr.stack_graphs([g]),
+                                   coarse_target=max(1024, 8 * k), seed=seed)
+    graphs = [gr.unstack_graph(lv.graph, 0) for lv in levels]
+    parts = initial.initial_partition(graphs[-1], k, seed=seed)
     for i in range(len(levels) - 1, -1, -1):
-        gi = levels[i].graph
+        gi = graphs[i]
         parts = _balance_only(gi, parts, k, lam)
         parts, _ = constrained_lp_refine(gi, parts, k, lam=lam, iters=24)
         if i > 0:
-            parts = co.project_partition(levels[i - 1].cmap, parts)
-            parts = jnp.where(levels[i - 1].graph.vertex_mask(), parts, k)
+            parts = co.project_partition(levels[i - 1].cmap[0], parts)
+            parts = jnp.where(graphs[i - 1].vertex_mask(), parts, k)
     return _balance_only(g, parts, k, lam)
 
 
@@ -112,60 +109,6 @@ def time_breakdown(quick=False):
                      100 * res.times["uncoarsen_s"] / tot))
         rows.append((f"breakdown/{name}/total_s", tot))
     return rows
-
-
-def coarsen_mode_ab(names=None, k=16, coarse_target=1024, reps=2,
-                    cfg_extra=None):
-    """Host-repack vs device-resident coarsening: per-phase wall time.
-
-    Each mode runs once cold (compile) then ``reps`` timed repetitions;
-    cuts must agree (both paths walk the same hierarchy).
-    """
-    if names is None:
-        names = list(SUITE)
-    graphs = {n: load(n) for n in names} if isinstance(names, list) else names
-    out = {}
-    for name, g in graphs.items():
-        rec = {}
-        for mode in ("host", "device"):
-            jax.clear_caches()
-            cfg = PartitionConfig(k=k, coarse_target=coarse_target,
-                                  coarsen_mode=mode, **(cfg_extra or {}))
-            res = partition(g, cfg)  # cold: includes compilation
-            timed = []
-            for _ in range(reps):
-                timed.append(partition(g, cfg))
-            cuts = {res.cut} | {t.cut for t in timed}
-            if len(cuts) != 1:
-                raise AssertionError(
-                    f"{name}/{mode}: nondeterministic cuts across reps {cuts}"
-                )
-            rec[mode] = {
-                "cut": res.cut,
-                "levels": res.levels,
-                "cold": res.times,
-                "warm": {
-                    ph: float(np.mean([t.times[ph] for t in timed]))
-                    for ph in ("coarsen_s", "uncoarsen_s", "total_s")
-                },
-                "level_capacity": [
-                    (st["n"], st["m"], st["n_max"], st["m_max"])
-                    for st in res.level_stats
-                ],
-            }
-        if rec["host"]["cut"] != rec["device"]["cut"]:
-            raise AssertionError(
-                f"{name}: host/device coarsening diverged — "
-                f"host cut {rec['host']['cut']} vs device "
-                f"{rec['device']['cut']}"
-            )
-        for phase in ("coarsen_s", "total_s"):
-            rec[f"speedup_{phase}"] = (
-                rec["host"]["warm"][phase]
-                / max(rec["device"]["warm"][phase], 1e-9)
-            )
-        out[name] = rec
-    return out
 
 
 def _rung_signatures(res):
@@ -263,11 +206,13 @@ def trials_ab(names=None, k=8, trials=4, coarse_target=512, cfg_extra=None):
 
 
 def _fleet_signatures(fres):
-    """Distinct ``uncoarsen_level_fleet`` compile signatures a fleet run
-    must have hit: (B, T, fine n_max, fine m_max, nc_max, c-ratio, ell
-    width).  The same counting rule as :func:`_rung_signatures`, extended
-    by the batch shape — two buckets with equal B and equal rungs SHARE
-    executables, which is the point of the shape-bucketed fleet."""
+    """Distinct ``uncoarsen_level`` compile signatures a fleet run must
+    have hit: (B, T, fine n_max, fine m_max, nc_max, c-ratio, ell width,
+    masked).  The same counting rule as :func:`_rung_signatures`, extended
+    by the batch shape and by whether some lane is frozen at the level (its
+    program then carries the lanes' refine flags) — two buckets with equal
+    B and equal rungs SHARE executables, which is the point of the
+    shape-bucketed fleet."""
     cfg = fres.config
     sigs = set()
     for b in fres.buckets:
@@ -276,7 +221,8 @@ def _fleet_signatures(fres):
             nc = st["n_max"] if j == 0 else b.level_stats[j - 1]["n_max"]
             c = cfg.c_finest if st["level"] == 0 else cfg.c_coarse
             md = st.get("ell_width") if cfg.backend == "ell" else None
-            sigs.add((B, fres.trials, st["n_max"], st["m_max"], nc, c, md))
+            sigs.add((B, fres.trials, st["n_max"], st["m_max"], nc, c, md,
+                      not st["active"].all()))
     return sigs
 
 
@@ -286,7 +232,7 @@ def fleet_ab(graphs=None, k=8, trials=1, coarse_target=512, cfg_extra=None):
 
     Gates: (1) every fleet member's cut, balance flag, and per-trial cuts
     are bit-identical to its standalone ``partition()`` run; (2) the fleet
-    compiles exactly one ``uncoarsen_level_fleet`` executable per (rung,
+    compiles exactly one ``uncoarsen_level`` executable per (rung,
     batch) signature — B and T ride batch axes, they never multiply
     executables; (3) the fleet exercises mixed bucket occupancy (some
     bucket holds graphs of different true sizes).
@@ -316,11 +262,11 @@ def fleet_ab(graphs=None, k=8, trials=1, coarse_target=512, cfg_extra=None):
     seq_warm_s = time.perf_counter() - t0
 
     jax.clear_caches()
-    execs0 = uncoarsen_level_fleet._cache_size()
+    execs0 = uncoarsen_level._cache_size()
     t0 = time.perf_counter()
     fres = partition_fleet(glist, PartitionConfig(**base))
     fleet_cold_s = time.perf_counter() - t0
-    execs = uncoarsen_level_fleet._cache_size() - execs0
+    execs = uncoarsen_level._cache_size() - execs0
     t0 = time.perf_counter()
     partition_fleet(glist, PartitionConfig(**base))
     fleet_warm_s = time.perf_counter() - t0
@@ -341,7 +287,7 @@ def fleet_ab(graphs=None, k=8, trials=1, coarse_target=512, cfg_extra=None):
     expected = len(_fleet_signatures(fres))
     if execs != expected:
         raise AssertionError(
-            f"{execs} uncoarsen_level_fleet executables for {expected} "
+            f"{execs} uncoarsen_level executables for {expected} "
             "bucket-rung signatures — fleet batching must not multiply "
             "compiles"
         )
@@ -384,10 +330,6 @@ def _cut_metrics(report):
     """Flatten the quality-critical numbers of a bench report:
     ``{metric_path: (cut value | balanced flag)}``."""
     cuts, balanced = {}, {}
-    for name, rec in report.get("coarsen_mode_ab", {}).items():
-        for mode in ("host", "device"):
-            if mode in rec:
-                cuts[f"coarsen_mode_ab/{name}/{mode}/cut"] = rec[mode]["cut"]
     for name, rec in report.get("trials_ab", {}).items():
         cuts[f"trials_ab/{name}/best_cut"] = rec["best_cut"]
         for t, c in enumerate(rec.get("trial_cuts", [])):
@@ -472,8 +414,7 @@ def check_baseline(baseline_path="BENCH_partitioner.json",
         os.remove(json_path)
     except OSError:
         pass
-    # a fresh smoke pass across all three A/Bs, merged into json_path
-    main(smoke=True, json_path=json_path)
+    # a fresh smoke pass across both A/Bs, merged into json_path
     main(smoke=True, json_path=json_path, trials=2)
     fresh = main(smoke=True, json_path=json_path, fleet=True)
     regressions = compare_baseline(fresh, baseline, tolerance=tolerance)
@@ -522,9 +463,9 @@ def main(quick=False, smoke=False, json_path="BENCH_partitioner.json",
     trials_full = trials or 4  # full-run default when --trials is omitted
     report = {}
     if smoke:
-        # CI guard: tiny graphs, one rep — exercises both coarsening modes
-        # (with --trials N, the batched best-of-N path; with --fleet, the
-        # shape-bucketed fleet path) end to end so the bench script can't
+        # CI guard: tiny graphs, one rep — exercises the batched
+        # best-of-N path (N = --trials, at least 2), or with --fleet the
+        # shape-bucketed fleet path, end to end so the bench script can't
         # silently rot.  Smoke runs MERGE into an existing report so the
         # smoke steps compose into one gate-able JSON.
         from repro.data import graphs as gen
@@ -543,18 +484,12 @@ def main(quick=False, smoke=False, json_path="BENCH_partitioner.json",
             )
             report.setdefault("fleet_ab", {})["smoke"] = fab
             print(json.dumps(fab, indent=1))
-        elif trials > 1:
+        else:
             tab = trials_ab(names={"smoke": gen.grid2d(16, 16)}, k=4,
-                            trials=trials, coarse_target=32,
+                            trials=max(trials, 2), coarse_target=32,
                             cfg_extra={"max_iter": 40, "patience": 4})
             report.setdefault("trials_ab", {}).update(tab)
             print(json.dumps(tab["smoke"], indent=1))
-        else:
-            ab = coarsen_mode_ab(names={"smoke": gen.grid2d(16, 16)}, k=4,
-                                 coarse_target=32, reps=1,
-                                 cfg_extra={"max_iter": 40, "patience": 4})
-            report.setdefault("coarsen_mode_ab", {}).update(ab)
-            print(json.dumps(ab["smoke"], indent=1))
         report.setdefault("baseline_tolerance", BASELINE_TOLERANCE)
         with open(json_path, "w") as f:
             json.dump(report, f, indent=1)
@@ -577,12 +512,6 @@ def main(quick=False, smoke=False, json_path="BENCH_partitioner.json",
     print("# Table 2-style phase breakdown (note: host-loop timings on CPU)")
     for name, v in rows2:
         print(f"{name},{v:.2f}")
-    ab = coarsen_mode_ab(names=["grid", "rmat"] if quick else None,
-                         reps=1 if quick else 2)
-    print("# coarsen A/B: host repack vs device-resident (warm total)")
-    for name, rec in ab.items():
-        print(f"coarsen_ab/{name}/coarsen_speedup,"
-              f"{rec['speedup_coarsen_s']:.3f}")
     tab = trials_ab(names=["grid", "rmat"] if quick else None,
                     trials=trials_full)
     print(f"# trials A/B: sequential {trials_full}-loop vs vmapped batch "
@@ -597,7 +526,6 @@ def main(quick=False, smoke=False, json_path="BENCH_partitioner.json",
     print(f"fleet_ab/mixed/bucket_executables,{fab['bucket_executables']}")
     report["quality"] = dict(rows)
     report["breakdown"] = dict(rows2)
-    report.setdefault("coarsen_mode_ab", {}).update(ab)
     report.setdefault("trials_ab", {}).update(tab)
     report.setdefault("fleet_ab", {})["mixed"] = fab
     report.setdefault("baseline_tolerance", BASELINE_TOLERANCE)
@@ -614,11 +542,11 @@ if __name__ == "__main__":
                     help="tiny graph, 1 rep — CI guard for the bench script")
     ap.add_argument("--trials", type=int, default=0,
                     help="trial count for the batched best-of-N A/B "
-                         "(default 4 for full runs); with --smoke, >1 runs "
-                         "the trials smoke instead of the coarsen-mode one")
+                         "(default 4 for full runs, at least 2 for the "
+                         "--smoke one)")
     ap.add_argument("--fleet", action="store_true",
                     help="with --smoke: run the shape-bucketed fleet A/B "
-                         "smoke instead of the coarsen-mode one")
+                         "smoke instead of the trials one")
     ap.add_argument("--check-baseline", action="store_true",
                     help="CI quality gate: run the smoke suite fresh and "
                          "exit nonzero if cut/balance regress against the "

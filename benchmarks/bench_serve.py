@@ -12,7 +12,7 @@ batch-occupancy histogram, and compile-cache hit counts.
 * at least one dispatched bucket had mixed occupancy (>= 2 real lanes
   holding different true sizes — the workload pairs near-sized grids on
   purpose);
-* exactly one ``uncoarsen_level_fleet`` executable per (rung, k)
+* exactly one ``uncoarsen_level`` executable per (rung, k)
   signature — the fixed-lanes discipline keeps the batch axis out of the
   compile key;
 * after the AOT warmup pass, replaying the workload compiles ZERO new
@@ -108,7 +108,7 @@ def serve_smoke(backends=("dense", "sorted", "ell"),
             raise AssertionError(
                 f"serve smoke [{backend}]: replay compiled "
                 f"{rep['post_warmup_new_executables']} new "
-                "uncoarsen_level_fleet executables after warmup — the AOT "
+                "uncoarsen_level executables after warmup — the AOT "
                 "grid must cover the workload"
             )
         # gate: one executable per (rung, k) signature — the AOT grid

@@ -49,7 +49,6 @@ import numpy as np  # noqa: E402
 
 from repro.core.partition import (  # noqa: E402
     PartitionConfig, partition, partition_fleet, uncoarsen_level,
-    uncoarsen_level_fleet,
 )
 from repro.data import graphs as gen  # noqa: E402
 from repro.launch.compile_cache import cache_stats, enable_compile_cache  # noqa: E402
@@ -153,10 +152,11 @@ def finest_level_text(g, res, cfg) -> str:
         return jax.ShapeDtypeStruct(shape, np.int32)
 
     lowered = uncoarsen_level.lower(
-        g, sds((fine["n_max"],)), sds((cfg.trials, coarse["n_max"])),
-        cfg.phi, k=cfg.k, lam=cfg.lam, c=cfg.c_finest, backend=cfg.backend,
-        patience=cfg.patience, max_iter=cfg.max_iter, b_max=cfg.b_max,
-        variant=cfg.variant, rebuild_every=cfg.rebuild_every,
+        jax.tree_util.tree_map(lambda x: sds((1,) + x.shape), g),
+        sds((1, fine["n_max"])), sds((1, cfg.trials, coarse["n_max"])),
+        None, cfg.phi, k=cfg.k, lam=cfg.lam, c=cfg.c_finest,
+        backend=cfg.backend, patience=cfg.patience, max_iter=cfg.max_iter,
+        b_max=cfg.b_max, variant=cfg.variant, rebuild_every=cfg.rebuild_every,
         max_degree=fine.get("max_degree") if cfg.backend == "ell" else None,
     )
     return lowered.compile().as_text()
@@ -263,10 +263,10 @@ def phase_served(families: dict, ks, copies: int, warmed: dict):
             return await asyncio.gather(*(
                 server.submit(families[name], k=k) for name, k in reqs))
 
-    execs0 = uncoarsen_level_fleet._cache_size()
+    execs0 = uncoarsen_level._cache_size()
     with _Window(all_threads=True) as win:
         results = asyncio.run(replay())
-    new_execs = uncoarsen_level_fleet._cache_size() - execs0
+    new_execs = uncoarsen_level._cache_size() - execs0
     _check(win.record["compiles"] == 0 and new_execs == 0,
            f"replay after warmup compiled {win.record['compiles']} programs")
     for (name, k), r in zip(reqs, results):

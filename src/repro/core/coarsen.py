@@ -5,22 +5,20 @@ add two-hop matches (leaves, twins, relatives).  Contraction (Alg 3.1)
 deduplicates coarse edges — the paper uses per-vertex hashtables; we use a
 lexicographic sort + segmented sum (TPU idiom, deterministic).
 
-Two coarsening paths share the matching/contraction kernels (DESIGN.md §8):
-
-* **device** (default): :func:`coarsen_level` runs a whole level — HEM
-  rounds, the two-hop trigger (``lax.cond`` on the device-computed
-  unmatched fraction), ``coarse_map``, ``contract_edges``, and the
-  coarse-CSR build — as ONE jitted function with zero host transfers.
-  The driver re-buckets the result into a precomputed geometric
-  :func:`shape_schedule` of (n_max, m_max) capacities, so kernels compile
-  once per capacity rung instead of once per exact size.  The only host
-  syncs left are one 6-int32 stat fetch per level: the coarse graph's
-  size (termination check + capacity selection) and the level's matching
-  counters (:data:`COUNTERS`).  The level's device phases carry the scopes
-  ``coarsen.hem``, ``coarsen.twohop``, ``coarsen.contract`` and
-  ``coarsen.csr``.
-* **host** (legacy): :func:`coarsen_once` repacks the coarse graph into
-  tight arrays on host via numpy — kept as the equivalence/bench baseline.
+Device-resident levels (DESIGN.md §8): :func:`coarsen_level` runs a whole
+level — HEM rounds, the two-hop trigger (``lax.cond`` on the
+device-computed unmatched fraction), ``coarse_map``, ``contract_edges``,
+and the coarse-CSR build — as ONE jitted function with zero host
+transfers, for every lane of a stacked ``(B, ...)`` graph
+(:func:`~repro.core.graph.map_lanes`).  :func:`multilevel_coarsen`
+re-buckets each level into a precomputed geometric :func:`shape_schedule`
+of (n_max, m_max) capacities, so kernels compile once per capacity rung
+instead of once per exact size.  The only host sync is one ``(B, 6)``
+int32 stat fetch per level: each lane's coarse size (termination check +
+capacity selection), its maximum degree, and the level's matching
+counters (:data:`COUNTERS`).  The level's device phases carry the scopes
+``coarsen.hem``, ``coarsen.twohop``, ``coarsen.contract`` and
+``coarsen.csr``.
 """
 from __future__ import annotations
 
@@ -31,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.graph import Graph, csr_from_edge_runs
+from repro.core.graph import Graph, csr_from_edge_runs, map_lanes
 from repro.core.spans import span
 
 _KNUTH = jnp.uint32(2654435761)
@@ -237,72 +235,26 @@ order: real vertices HEM left unmatched, whether the two-hop pass ran
 
 
 class CoarsenLevel(NamedTuple):
+    """One level of a stacked ``(B, ...)`` hierarchy.
+
+    ``cmap`` is ``(B, n_max)`` into the next level (identity rows for
+    frozen lanes; None at the coarsest level).  ``active[b]`` says lane
+    ``b`` is still *real* at this level — its own hierarchy reaches this
+    deep, so the uncoarsening driver refines it here; frozen lanes pass
+    their partition through untouched.  ``stats`` holds per-lane host
+    numbers as ``(B,)`` arrays — ``n``, ``m``, ``max_degree``, the
+    :data:`COUNTERS` of the matching run on this graph, and ``counted``,
+    whether one ran for that lane — plus the shared ``n_max``/``m_max``.
+    """
+
     graph: Graph
-    cmap: jnp.ndarray  # fine vertex -> coarse vertex of the NEXT level
-    # host ints: n, m, max_degree, n_max, m_max, and on the device path the
-    # COUNTERS of the matching run on this graph, where one ran
-    stats: dict | None = None
+    cmap: jnp.ndarray | None
+    active: np.ndarray
+    stats: dict
 
 
 def _round_up(x: int, mult: int = 8) -> int:
     return ((x + mult - 1) // mult) * mult
-
-
-def coarsen_once(
-    g: Graph,
-    twohop_threshold: float = 0.25,
-    mm_max_degree: int = 64,
-    seed: int = 0,
-) -> tuple[Graph, jnp.ndarray]:
-    """One coarsening level, legacy host-repack path.
-
-    Returns (coarse graph (tight arrays), cmap).  Kept as the equivalence
-    baseline for :func:`coarsen_level`; prefer the device path in drivers.
-    """
-    match = heavy_edge_matching(g, seed=seed)
-    n = int(g.n)
-    unmatched = int(
-        np.asarray(jnp.sum(((match < 0) & g.vertex_mask()).astype(jnp.int32)))
-    )
-    # float32 on purpose: bit-identical to coarsen_level's on-device trigger
-    # (a float64 division here could disagree near the threshold for huge n)
-    frac = np.float32(unmatched) / np.float32(max(n, 1))
-    if frac > np.float32(twohop_threshold):
-        match = twohop_matching(g, match, mm_max_degree, seed)
-    cmap, nc_dev = coarse_map(g, match)
-    cu_run, cv_run, w_run, run_valid, n_runs_dev, vwgt_c = contract_edges(g, cmap)
-    nc = int(nc_dev)
-    n_runs = int(n_runs_dev)
-    # host repack into tight padded arrays
-    cu = np.asarray(cu_run)[:n_runs]
-    cv = np.asarray(cv_run)[:n_runs]
-    w = np.asarray(w_run)[:n_runs]
-    vw = np.asarray(vwgt_c)[:nc]
-    n_max_c = _round_up(max(nc, 1))
-    m_max_c = _round_up(max(n_runs, 1))
-    xadj = np.zeros(n_max_c + 1, dtype=np.int64)
-    np.add.at(xadj, cu + 1, 1)
-    xadj = np.cumsum(xadj)
-    xadj_p = np.full(n_max_c + 1, n_runs, dtype=np.int32)
-    xadj_p[: nc + 1] = xadj[: nc + 1]
-    adjncy_p = np.zeros(m_max_c, dtype=np.int32)
-    adjncy_p[:n_runs] = cv
-    adjwgt_p = np.zeros(m_max_c, dtype=np.int32)
-    adjwgt_p[:n_runs] = w
-    vwgt_p = np.zeros(n_max_c, dtype=np.int32)
-    vwgt_p[:nc] = vw
-    esrc_p = np.zeros(m_max_c, dtype=np.int32)
-    esrc_p[:n_runs] = cu
-    gc = Graph(
-        xadj=jnp.asarray(xadj_p),
-        adjncy=jnp.asarray(adjncy_p),
-        adjwgt=jnp.asarray(adjwgt_p),
-        vwgt=jnp.asarray(vwgt_p),
-        esrc=jnp.asarray(esrc_p),
-        n=jnp.asarray(nc, dtype=jnp.int32),
-        m=jnp.asarray(n_runs, dtype=jnp.int32),
-    )
-    return gc, cmap
 
 
 # ---------------------------------------------------------------------------
@@ -310,29 +262,18 @@ def coarsen_once(
 # ---------------------------------------------------------------------------
 
 
-@partial(jax.jit, static_argnames=("hem_rounds",))
-def coarsen_level(
-    g: Graph,
-    seed: int = 0,
-    twohop_threshold: float = 0.25,
-    mm_max_degree: int = 64,
-    hem_rounds: int = 8,
-) -> tuple[Graph, jnp.ndarray]:
-    """One whole coarsening level as a single jitted function — no host syncs.
+def _graph_stats(g: Graph) -> jnp.ndarray:
+    """(n, m, max_degree) of one graph as one int32 array."""
+    return jnp.stack(
+        [g.n, g.m, jnp.max(g.degrees()).astype(jnp.int32)]
+    ).astype(jnp.int32)
 
-    HEM rounds, the two-hop trigger (``lax.cond`` on the device-computed
-    unmatched fraction), ``coarse_map``, ``contract_edges``, and the
-    device-side coarse-CSR build all run in one XLA program.  The coarse
-    graph comes back padded at the FINE graph's capacities (``nc <= n`` and
-    ``n_runs <= m`` guarantee they fit); the driver re-buckets it with
-    :meth:`Graph.with_capacity` after reading the level stats.
 
-    ``seed``/``twohop_threshold``/``mm_max_degree`` are traced, so changing
-    them never recompiles; only the capacity bucket (array shapes) does.
-
-    Returns ``(gc, cmap, counts)``: ``counts`` is an int32 (3,) array of
-    the level's :data:`COUNTERS`, computed on the device.
-    """
+def _coarsen_graph(g: Graph, seed, twohop_threshold, mm_max_degree,
+                   hem_rounds: int):
+    """One level of one graph: the coarse graph at ``g``'s capacities, the
+    cmap, and the coarse graph's ``_graph_stats`` followed by the level's
+    :data:`COUNTERS`."""
     vmask = g.vertex_mask()
     with jax.named_scope("coarsen.hem"):
         match = heavy_edge_matching(g, rounds=hem_rounds, seed=seed)
@@ -360,43 +301,64 @@ def coarsen_level(
         )
     counts = jnp.stack([unmatched, twohop.astype(jnp.int32),
                         (unmatched - left) // 2])
-    return gc, cmap, counts
+    return gc, cmap, jnp.concatenate([_graph_stats(gc), counts])
+
+
+@partial(jax.jit, static_argnames=("hem_rounds",))
+def coarsen_level(
+    gb: Graph,
+    seed: int = 0,
+    twohop_threshold: float = 0.25,
+    mm_max_degree: int = 64,
+    hem_rounds: int = 8,
+) -> tuple[Graph, jnp.ndarray, jnp.ndarray]:
+    """One whole coarsening level of every lane of a stacked ``(B, ...)``
+    graph as a single jitted function — no host syncs.
+
+    HEM rounds, the two-hop trigger (``lax.cond`` on the device-computed
+    unmatched fraction; a select where B > 1), ``coarse_map``,
+    ``contract_edges``, and the device-side coarse-CSR build all run in
+    one XLA program.  The coarse graphs come back padded at the FINE
+    capacities (``nc <= n`` and ``n_runs <= m`` guarantee they fit); the
+    driver re-buckets them after reading the level stats.
+
+    ``seed``/``twohop_threshold``/``mm_max_degree`` are traced and shared
+    by every lane, so changing them never recompiles; only the capacity
+    bucket (array shapes) does.
+
+    Returns ``(gc, cmap, stats)``: ``stats`` is ``(B, 6)`` int32, each
+    lane's coarse ``n``, ``m`` and maximum degree, then the level's
+    :data:`COUNTERS`, all computed on the device.
+    """
+    return map_lanes(
+        lambda g: _coarsen_graph(g, seed, twohop_threshold, mm_max_degree,
+                                 hem_rounds), gb)
 
 
 @jax.jit
-def _level_stats_dev(g: Graph) -> jnp.ndarray:
-    """(n, m, max_degree) as one int32 device array — fetched in ONE transfer."""
-    return jnp.stack(
-        [g.n, g.m, jnp.max(g.degrees()).astype(jnp.int32)]
-    ).astype(jnp.int32)
-
-
-@jax.jit
-def _level_stats_counts_dev(g: Graph, counts: jnp.ndarray) -> jnp.ndarray:
-    """:func:`_level_stats_dev` of the coarse graph followed by the
-    counters of the level that made it, for the same ONE transfer."""
-    return jnp.concatenate([_level_stats_dev(g), counts])
+def _lane_stats(gb: Graph) -> jnp.ndarray:
+    """(B, 3) int32 ``_graph_stats`` of every lane, for ONE transfer."""
+    return map_lanes(_graph_stats, gb)
 
 
 @partial(jax.jit, static_argnames=("n_max", "m_max"))
-def _rebucket(g: Graph, n_max: int, m_max: int) -> Graph:
-    return g.with_capacity(n_max, m_max)
+def _rebucket(gb: Graph, n_max: int, m_max: int) -> Graph:
+    return map_lanes(lambda g: g.with_capacity(n_max, m_max), gb)
 
 
-def _fetch_stats(g: Graph, level: int = 0,
-                 counts: jnp.ndarray | None = None) -> tuple[dict, dict]:
-    """Host stats of ``g``, and the :data:`COUNTERS` of the level that
-    made it where ``counts`` is given (else empty), in one transfer."""
-    with span("coarsen.fetch", level=level):
-        if counts is None:
-            vals = [int(x) for x in np.asarray(_level_stats_dev(g))]
-        else:
-            vals = [int(x) for x in
-                    np.asarray(_level_stats_counts_dev(g, counts))]
-    n, m, max_deg = vals[:3]
-    return ({"n": n, "m": m, "max_degree": max_deg,
-             "n_max": g.n_max, "m_max": g.m_max},
-            dict(zip(COUNTERS, vals[3:])))
+@jax.jit
+def _freeze(gc: Graph, cmap: jnp.ndarray, fine: Graph,
+            success: jnp.ndarray) -> tuple[Graph, jnp.ndarray]:
+    """Select-mask the lanes that did not coarsen back to their fine graph
+    with an identity cmap: the batched form of a lone graph's ``break``."""
+
+    def one(gc_i, cmap_i, fine_i, s):
+        g = jax.tree_util.tree_map(lambda a, b: jnp.where(s, a, b),
+                                   gc_i, fine_i)
+        ident = jnp.arange(cmap_i.shape[0], dtype=jnp.int32)
+        return g, jnp.where(s, cmap_i, ident)
+
+    return map_lanes(one, gc, cmap, fine, success)
 
 
 def shape_schedule(
@@ -460,236 +422,91 @@ def select_capacity(
     return (n_cap, m_cap)
 
 
-# ---------------------------------------------------------------------------
-# Fleet coarsening — vmapped levels over a shape bucket (DESIGN.md §10)
-# ---------------------------------------------------------------------------
-
-
-class FleetLevel(NamedTuple):
-    """One level of a bucket's batched hierarchy.
-
-    ``graph`` is a stacked ``(B, ...)`` :class:`Graph`; ``cmap`` is
-    ``(B, n_max)`` into the next level (identity rows for frozen lanes;
-    None at the coarsest level).  ``active[b]`` says lane ``b`` is still
-    *real* at this level — its own hierarchy reaches this deep, so the
-    uncoarsening driver runs refinement for it here; frozen lanes pass
-    their partition through untouched.  ``stats`` holds per-lane host
-    numbers (``n``/``m``/``max_degree`` as (B,) arrays) plus the shared
-    ``n_max``/``m_max`` capacity ints.
-    """
-
-    graph: Graph
-    cmap: jnp.ndarray | None
-    active: np.ndarray
-    stats: dict | None
-
-
-@jax.jit
-def _stats_fleet(gb: Graph) -> jnp.ndarray:
-    """(B, 3) int32 per-lane (n, m, max_degree) — one transfer per level."""
-    return jax.vmap(_level_stats_dev)(gb)
-
-
-@jax.jit
-def _coarsen_step_fleet(gb: Graph, seed, twohop_threshold, mm_max_degree):
-    """One coarsening level for every lane of a bucket, plus its stats.
-
-    ``seed``/thresholds are traced scalars shared by all lanes, exactly as
-    the standalone driver passes them — a lane's matching trajectory is the
-    one its solo run would walk (the two-hop ``lax.cond`` select-masks per
-    lane under vmap).
-    """
-
-    def one(g):
-        gc, cmap, _ = coarsen_level(g, seed, twohop_threshold, mm_max_degree)
-        return gc, cmap, _level_stats_dev(gc)
-
-    return jax.vmap(one)(gb)
-
-
-@partial(jax.jit, static_argnames=("n_max", "m_max"))
-def _freeze_rebucket_fleet(
-    gc: Graph, cmap: jnp.ndarray, fine: Graph, success: jnp.ndarray,
-    *, n_max: int, m_max: int,
-) -> tuple[Graph, jnp.ndarray]:
-    """Select-mask failed lanes back to their fine graph, then re-bucket.
-
-    Lanes that terminated (reached ``coarse_target`` earlier, or stalled
-    this level) keep their fine graph frozen with an identity cmap — the
-    batched analogue of the standalone driver's ``break``.  All lanes are
-    then re-bucketed to the shared next capacity, which is selected to fit
-    the batch max per axis, so frozen lanes always fit.
-    """
-
-    def one(gc_i, cmap_i, fine_i, s):
-        g = jax.tree_util.tree_map(
-            lambda a, b: jnp.where(s, a, b), gc_i, fine_i
-        )
-        ident = jnp.arange(cmap_i.shape[0], dtype=jnp.int32)
-        return g.with_capacity(n_max, m_max), jnp.where(s, cmap_i, ident)
-
-    return jax.vmap(one)(gc, cmap, fine, success)
-
-
-def multilevel_coarsen_fleet(
+def multilevel_coarsen(
     gb: Graph,
-    schedule: tuple[tuple[int, int], ...],
+    schedule: tuple[tuple[int, int], ...] | None = None,
     coarse_target: int = 4096,
     max_levels: int = 40,
     stall_ratio: float = 0.95,
     seed: int = 0,
     twohop_threshold: float = 0.25,
     mm_max_degree: int = 64,
-) -> list[FleetLevel]:
-    """Batched MLCoarsen over one shape bucket: list of levels, finest first.
+) -> list[CoarsenLevel]:
+    """MLCoarsen (Alg 2.1 line 1) of a stacked ``(B, ...)`` bucket: list of
+    levels, finest first.
 
-    The whole bucket advances in lockstep — batch level ``i`` is every
-    lane's own level ``i`` — but each lane terminates on ITS own schedule
-    (``coarse_target`` / ``stall_ratio`` / ``max_levels``), mirroring the
-    standalone driver's per-graph ``break``s via select-masking: a
-    terminated lane's graph rides along frozen (identity cmap) and its
-    ``active`` flag goes false for all deeper levels.  Per-level host syncs
-    are one (B, 3) stat fetch, same cadence as the standalone driver.
+    ``levels[i].cmap`` maps level-i vertices into level-(i+1)'s graph.
+    The bucket advances in lockstep — batch level ``i`` is every lane's own
+    level ``i``, with the seed ``seed + i`` a lone run would use — but each
+    lane terminates on ITS own schedule (``coarse_target`` /
+    ``stall_ratio`` / ``max_levels``): a terminated lane rides along frozen
+    with an identity cmap, and its ``active`` flag goes false for all
+    deeper levels.  A lane's level carries its :data:`COUNTERS` where its
+    matching ran: every level but its coarsest, and the coarsest too where
+    its own attempt stalled.
+
+    Each level's capacity is the smallest ``schedule`` rung (a
+    :func:`shape_schedule` ladder; by default one from the bucket's
+    capacity) that fits the batch max per axis.  The host syncs are one
+    ``(B, 3)`` stat fetch of the input and one ``(B, 6)`` fetch per level;
+    the freeze runs only when some lane did not coarsen, the re-bucket
+    only when the capacity changes, so a one-lane bucket runs exactly
+    ``coarsen_level`` and ``_rebucket`` per level.
     """
     B = gb.vwgt.shape[0]
     n_max, m_max = gb.vwgt.shape[1], gb.adjncy.shape[1]
+    if schedule is None:
+        schedule = shape_schedule(n_max, m_max, stall_ratio=stall_ratio)
     with span("coarsen.fetch", level=0):
-        st0 = np.asarray(_stats_fleet(gb))
-    n, m, md = (st0[:, j].astype(np.int64) for j in range(3))
-    if schedule[0][0] < n_max or schedule[0][1] < m_max:
+        st = np.asarray(_lane_stats(gb)).astype(np.int64)
+    if schedule[0][0] < st[:, 0].max() or schedule[0][1] < st[:, 1].max():
         raise ValueError(
-            f"schedule rung 0 {schedule[0]} is below the bucket capacity "
-            f"({n_max}, {m_max}) — bucket with bucket_graphs first"
+            f"schedule rung 0 {schedule[0]} cannot hold the input graphs "
+            f"(n={st[:, 0].max()}, m={st[:, 1].max()}) — with_capacity "
+            "would silently truncate real vertices/edges"
         )
     dead = np.zeros(B, bool)
     depth = np.zeros(B, np.int64)
-    raw: list[tuple] = []
+    raw: list[tuple] = []  # (graph, cmap, stats), finest first
+    stalled = None  # stats of a level at which every attempt stalled
     for lvl in range(max_levels):
-        active = ~dead & (n > coarse_target)
-        if not active.any():
+        attempt = ~dead & (st[:, 0] > coarse_target)
+        if not attempt.any():
             break
         with span("coarsen.level", level=lvl, n_max=n_max, m_max=m_max):
-            gc, cmap, stc = _coarsen_step_fleet(
-                gb, seed + lvl, twohop_threshold, mm_max_degree
-            )
+            gc, cmap, stc = coarsen_level(
+                gb, seed + lvl, twohop_threshold, mm_max_degree)
             with span("coarsen.fetch", level=lvl + 1):
                 stc = np.asarray(stc).astype(np.int64)  # per-level host sync
-            stalled = stc[:, 0] > stall_ratio * n
-            success = active & ~stalled
-            dead |= active & stalled
-            if not success.any():
+            success = attempt & (stc[:, 0] <= stall_ratio * st[:, 0])
+            dead |= attempt & ~success
+            stats = _host_stats(st, n_max, m_max, stc[:, 3:], attempt)
+            if not success.any():  # gb is every lane's coarsest level
+                stalled = stats
                 break
-            new_n = np.where(success, stc[:, 0], n)
-            new_m = np.where(success, stc[:, 1], m)
-            new_md = np.where(success, stc[:, 2], md)
-            cap = select_capacity(schedule, int(new_n.max()),
-                                  int(new_m.max()))
-            gb2, cmap = _freeze_rebucket_fleet(
-                gc, cmap, gb, jnp.asarray(success), n_max=cap[0],
-                m_max=cap[1]
-            )
-        raw.append((gb, cmap,
-                    {"n": n, "m": m, "max_degree": md,
-                     "n_max": n_max, "m_max": m_max}))
+            if not success.all():
+                gc, cmap = _freeze(gc, cmap, gb, jnp.asarray(success))
+            st = np.where(success[:, None], stc[:, :3], st)
+            cap = select_capacity(schedule, int(st[:, 0].max()),
+                                  int(st[:, 1].max()))
+            if cap != (n_max, m_max):
+                gc = _rebucket(gc, *cap)
+        raw.append((gb, cmap, stats))
         depth += success
-        gb, n, m, md = gb2, new_n, new_m, new_md
-        n_max, m_max = cap
-    raw.append((gb, None, {"n": n, "m": m, "max_degree": md,
-                           "n_max": n_max, "m_max": m_max}))
-    return [
-        FleetLevel(graph=g, cmap=c, active=depth >= i, stats=s)
-        for i, (g, c, s) in enumerate(raw)
-    ]
+        gb, (n_max, m_max) = gc, cap
+    if stalled is None:  # no lane attempted the coarsest level
+        stalled = _host_stats(st, n_max, m_max,
+                               np.zeros((B, len(COUNTERS)), np.int64),
+                               np.zeros(B, bool))
+    raw.append((gb, None, stalled))
+    return [CoarsenLevel(graph=g, cmap=c, active=depth >= i, stats=s)
+            for i, (g, c, s) in enumerate(raw)]
 
 
-def multilevel_coarsen(
-    g: Graph,
-    coarse_target: int = 4096,
-    max_levels: int = 40,
-    stall_ratio: float = 0.95,
-    seed: int = 0,
-    mode: str = "device",
-    schedule: tuple[tuple[int, int], ...] | None = None,
-    twohop_threshold: float = 0.25,
-    mm_max_degree: int = 64,
-    bucket_ratio: float = 1.6,
-    bucket_safety: float = 1.25,
-    bucket_align: int = 64,
-) -> list[CoarsenLevel]:
-    """MLCoarsen (Alg 2.1 line 1): list of levels, finest first.
-
-    ``levels[i].cmap`` maps level-i vertices into level-(i+1)'s graph.
-    The last entry's cmap is None (coarsest graph).  Every level carries
-    host ``stats`` (n, m, max_degree, capacities) captured in one per-level
-    transfer, so downstream consumers (ELL backend, ConnState build) never
-    re-sync.  On the device path a level's stats also hold the
-    :data:`COUNTERS` of the matching run on its graph: every level but the
-    coarsest, and the coarsest too where its own attempt stalled.
-
-    ``mode="device"`` (default) runs each level via :func:`coarsen_level`
-    and re-buckets results along ``schedule`` (a :func:`shape_schedule`
-    ladder); the only host decisions are the termination check and the
-    capacity selection.  ``mode="host"`` is the legacy per-level numpy
-    repack via :func:`coarsen_once`.
-    """
-    if mode not in ("device", "host"):
-        raise ValueError(f"unknown coarsen mode {mode!r}")
-    cur = g
-    stats0, _ = _fetch_stats(cur)
-    if mode == "device":
-        if schedule is None:
-            schedule = shape_schedule(
-                g.n_max, g.m_max, ratio=bucket_ratio, safety=bucket_safety,
-                stall_ratio=stall_ratio, align=bucket_align,
-            )
-        if schedule[0][0] < stats0["n"] or schedule[0][1] < stats0["m"]:
-            raise ValueError(
-                f"schedule rung 0 {schedule[0]} cannot hold the input graph "
-                f"(n={stats0['n']}, m={stats0['m']}) — with_capacity would "
-                "silently truncate real vertices/edges"
-            )
-        if (cur.n_max, cur.m_max) != schedule[0]:
-            cur = _rebucket(cur, *schedule[0])
-            stats0 = {**stats0, "n_max": schedule[0][0],
-                      "m_max": schedule[0][1]}
-
-    def step(fine, lvl):
-        """One level, its stats and the fine level's counters; per-level
-        host syncs live here."""
-        if mode == "host":
-            gc, cmap = coarsen_once(
-                fine, twohop_threshold=twohop_threshold,
-                mm_max_degree=mm_max_degree, seed=seed + lvl,
-            )
-            return (gc, cmap) + _fetch_stats(gc, lvl + 1)
-        gc, cmap, counts = coarsen_level(
-            fine, seed=seed + lvl, twohop_threshold=twohop_threshold,
-            mm_max_degree=mm_max_degree,
-        )
-        # The ONLY device-path host sync: 6 int32 (termination + capacity,
-        # and the counters).
-        st, counted = _fetch_stats(gc, lvl + 1, counts)
-        cap = select_capacity(schedule, st["n"], st["m"])
-        if cap != (gc.n_max, gc.m_max):
-            gc = _rebucket(gc, *cap)
-            st = {**st, "n_max": cap[0], "m_max": cap[1]}
-        return gc, cmap, st, counted
-
-    levels: list[CoarsenLevel] = []
-    stats = stats0
-    for lvl in range(max_levels):
-        if stats["n"] <= coarse_target:
-            break
-        with span("coarsen.level", level=lvl, n_max=cur.n_max,
-                  m_max=cur.m_max):
-            gc, cmap, stats_c, counted = step(cur, lvl)
-        stats = stats | counted
-        if stats_c["n"] > stall_ratio * stats["n"]:  # stalled
-            break
-        levels.append(CoarsenLevel(graph=cur, cmap=cmap, stats=stats))
-        cur, stats = gc, stats_c
-    levels.append(CoarsenLevel(graph=cur, cmap=None, stats=stats))
-    return levels
+def _host_stats(st, n_max, m_max, counts, counted) -> dict:
+    return {"n": st[:, 0], "m": st[:, 1], "max_degree": st[:, 2],
+            "n_max": n_max, "m_max": m_max, "counted": counted,
+            **dict(zip(COUNTERS, counts.T))}
 
 
 def project_partition(cmap: jnp.ndarray, parts_coarse: jnp.ndarray) -> jnp.ndarray:

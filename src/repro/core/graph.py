@@ -14,6 +14,7 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -91,10 +92,11 @@ def stack_graphs(graphs: "list[Graph]") -> Graph:
     """Stack same-capacity graphs along a leading batch axis.
 
     The result is a plain :class:`Graph` pytree whose every leaf carries a
-    leading ``(B, ...)`` axis — built for ``jax.vmap`` consumers (the fleet
-    drivers).  The ``n_max`` / ``m_max`` properties read leaf ``shape[0]``
-    and are therefore meaningless on a stacked graph; use the per-leaf
-    shapes (``vwgt.shape == (B, N)``) or :func:`unstack_graph` instead.
+    leading ``(B, ...)`` axis — the lane axis of the V-cycle driver, which
+    maps its per-graph steps over it with :func:`map_lanes`.  The
+    ``n_max`` / ``m_max`` properties read leaf ``shape[0]`` and are
+    therefore meaningless on a stacked graph; use the per-leaf shapes
+    (``vwgt.shape == (B, N)``) or :func:`unstack_graph` instead.
     """
     if not graphs:
         raise ValueError("stack_graphs needs at least one graph")
@@ -113,6 +115,23 @@ def stack_graphs(graphs: "list[Graph]") -> Graph:
 def unstack_graph(gb: Graph, b: int) -> Graph:
     """Member ``b`` of a stacked graph (device-side slice, no copy)."""
     return Graph(*(leaf[b] for leaf in gb))
+
+
+def map_lanes(fn, *args):
+    """``fn`` applied along the leading axis of every leaf of ``args``.
+
+    The one batching rule of the V-cycle (DESIGN.md §9): an axis of size 1
+    is squeezed and ``fn`` runs unbatched, so its ``lax.cond``s stay conds
+    and compute only the branch taken; any other size is ``jax.vmap``ped,
+    where a batched predicate turns a cond into a select.  The values are
+    the same either way.  Per-graph steps map it over the lane axis B,
+    per-trial steps over the trial axis T; ``None`` arguments pass through.
+    """
+    size = jax.tree_util.tree_leaves(args)[0].shape[0]
+    if size == 1:
+        out = fn(*jax.tree_util.tree_map(lambda x: x[0], args))
+        return jax.tree_util.tree_map(lambda x: x[None], out)
+    return jax.vmap(fn)(*args)
 
 
 def bucket_graphs(
@@ -144,8 +163,6 @@ def bucket_graphs(
     member).  Admission is a host decision, so it costs one blocking fetch
     of all (n, m) pairs here — the last admission sync before results.
     """
-    import jax
-
     from repro.core.coarsen import select_capacity, shape_schedule, _round_up
 
     if not graphs:

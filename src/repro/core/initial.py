@@ -11,9 +11,9 @@ at the coarsest level (the multilevel driver always refines level l):
   connected-ish parts that refinement improves much faster.
 
 Both methods are seeded with a *traced* int32 scalar — all hashing is
-elementwise integer arithmetic, so :func:`initial_partition_batch` can vmap
-one trace over a whole batch of trial seeds (DESIGN.md §9) and trial ``t``
-of the batch is bit-identical to the scalar call with ``seeds[t]``.
+elementwise integer arithmetic, so :func:`initial_partition_batch` can map
+one trace over a batch of graphs and trial seeds (DESIGN.md §9) and trial
+``t`` of the batch is bit-identical to the scalar call with ``seeds[t]``.
 """
 from __future__ import annotations
 
@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import connectivity as cn
-from repro.core.graph import Graph
+from repro.core.graph import Graph, map_lanes
 
 _KNUTH = jnp.uint32(2654435761)
 # Padding sort key: strictly above every real vertex key (real keys are
@@ -130,52 +130,29 @@ def initial_partition(g: Graph, k: int, seed=0, method: str = "voronoi"):
 
 
 @partial(jax.jit, static_argnames=("k", "method"))
-def _initial_batch(g: Graph, seeds: jnp.ndarray, k: int, method: str):
+def _initial_batch(gb: Graph, seeds: jnp.ndarray, k: int, method: str):
     fn = random_partition if method == "random" else voronoi_partition
     with jax.named_scope("initial"):
-        return jax.vmap(lambda s: fn(g, k, s))(seeds)
+        return map_lanes(
+            lambda g: map_lanes(lambda s: fn(g, k, s), seeds), gb)
 
 
 def initial_partition_batch(
-    g: Graph, k: int, seeds, method: str = "voronoi"
-) -> jnp.ndarray:
-    """(T, n_max) int32 batch of seeded initial partitions in ONE trace.
-
-    Row ``t`` is bit-identical to ``initial_partition(g, k, seeds[t])`` —
-    the hashing is elementwise integer arithmetic and the BFS while-loop's
-    batching rule freezes each trial's carry once its own condition goes
-    false, so vmap changes the schedule, never the values (DESIGN.md §9).
-    """
-    if method not in METHODS:
-        raise ValueError(f"unknown initial partition method {method!r}")
-    seeds = jnp.asarray(seeds, dtype=jnp.int32)
-    if seeds.ndim != 1:
-        raise ValueError(f"seeds must be 1-D (one per trial), got {seeds.shape}")
-    return _initial_batch(g, seeds, k, method)
-
-
-@partial(jax.jit, static_argnames=("k", "method"))
-def _initial_fleet(gb: Graph, seeds: jnp.ndarray, k: int, method: str):
-    fn = random_partition if method == "random" else voronoi_partition
-    with jax.named_scope("initial"):
-        return jax.vmap(
-            lambda g: jax.vmap(lambda s: fn(g, k, s))(seeds))(gb)
-
-
-def initial_partition_fleet(
     gb: Graph, k: int, seeds, method: str = "voronoi"
 ) -> jnp.ndarray:
-    """(B, T, n_max) seeded initial partitions over a stacked graph batch.
+    """(B, T, n_max) int32 seeded initial partitions of a stacked
+    ``(B, ...)`` graph in ONE trace.
 
     Lane ``b``, trial ``t`` is bit-identical to
-    ``initial_partition(unstack_graph(gb, b), k, seeds[t])`` — the same
-    §9 argument as :func:`initial_partition_batch`, lifted over the graph
-    axis (all hashing is elementwise and mask-aware, so a lane's values
-    never depend on its padding or on its bucket-mates).
+    ``initial_partition(unstack_graph(gb, b), k, seeds[t])`` — the hashing
+    is elementwise integer arithmetic and mask-aware, and the BFS
+    while-loop's batching rule freezes each lane's and trial's carry once
+    its own condition goes false, so batching changes the schedule, never
+    the values (DESIGN.md §9).
     """
     if method not in METHODS:
         raise ValueError(f"unknown initial partition method {method!r}")
     seeds = jnp.asarray(seeds, dtype=jnp.int32)
     if seeds.ndim != 1:
         raise ValueError(f"seeds must be 1-D (one per trial), got {seeds.shape}")
-    return _initial_fleet(gb, seeds, k, method)
+    return _initial_batch(gb, seeds, k, method)

@@ -156,9 +156,10 @@ def jet_refine(
 ):
     """Alg 4.1. Returns (best_parts, stats dict).
 
-    Host-side wrapper: normalizes the input partition, builds the per-level
-    ConnState (unless the caller — e.g. the multilevel driver — already owns
-    one), resolves the static ELL width, then enters the jitted loop.
+    Host-side wrapper: normalizes the input partition, builds the ConnState
+    (unless the caller already owns one), resolves the static ELL width,
+    then enters the jitted loop.  The multilevel driver does not come here:
+    its levels fuse the same steps into ``partition.uncoarsen_level``.
     """
     if rebuild_every < 0:
         raise ValueError(f"rebuild_every must be >= 0, got {rebuild_every}")

@@ -106,11 +106,6 @@ def main(argv=None):
                     help="stop coarsening at this many vertices")
     ap.add_argument("--max-levels", type=int, default=40,
                     help="coarsening depth cap")
-    ap.add_argument("--coarsen-mode", default="device",
-                    choices=["device", "host"],
-                    help="device = jitted levels on a static shape schedule; "
-                         "host = legacy per-level numpy repack (single-graph "
-                         "mode only)")
     ap.add_argument("--bucket-ratio", type=float, default=1.6,
                     help="shape-schedule geometric shrink per rung")
     ap.add_argument("--bucket-safety", type=float, default=1.25,
@@ -145,7 +140,6 @@ def main(argv=None):
                           rebuild_every=args.rebuild_every, seed=args.seed,
                           coarse_target=args.coarse_target,
                           max_levels=args.max_levels,
-                          coarsen_mode=args.coarsen_mode,
                           bucket_ratio=args.bucket_ratio,
                           bucket_safety=args.bucket_safety,
                           bucket_align=args.bucket_align,

@@ -41,7 +41,7 @@ from repro.core import graph as gr
 from repro.core.coarsen import _round_up, shape_schedule
 from repro.core.partition import (
     PartitionConfig, PartitionResult, partition_fleet_stacked,
-    uncoarsen_level_fleet,
+    uncoarsen_level,
 )
 from repro.core.spans import span
 from repro.launch.compile_cache import CompileCacheStats, cache_stats
@@ -310,9 +310,12 @@ class PartitionServer:
                                                          sb.orig_n_max)
                                      if t is not None],
                     "levels": fb.levels,
+                    # "masked": some lane is frozen at the level, so its
+                    # program carries the lanes' refine flags
                     "level_stats": [
                         {kk: st[kk] for kk in ("level", "n_max", "m_max",
                                                "ell_width") if kk in st}
+                        | {"masked": not bool(st["active"].all())}
                         for st in fb.level_stats
                     ],
                 }
@@ -375,7 +378,7 @@ class PartitionServer:
 
         stats = cache_stats()
         before_cache = stats.snapshot()
-        before_exec = uncoarsen_level_fleet._cache_size()
+        before_exec = uncoarsen_level._cache_size()
         t0 = time.perf_counter()
         for k in ks:
             for t in trials:
@@ -393,7 +396,7 @@ class PartitionServer:
         return {
             "warmup_s": time.perf_counter() - t0,
             "signatures": [(k, t) for k in ks for t in trials],
-            "new_executables": uncoarsen_level_fleet._cache_size()
+            "new_executables": uncoarsen_level._cache_size()
             - before_exec,
             "cache_events": CompileCacheStats.delta(before_cache,
                                                     stats.snapshot()),
@@ -429,16 +432,16 @@ class PartitionServer:
             if wait else 0.0,
             "p90_queue_wait_ms": 1e3 * float(np.percentile(wait, 90))
             if wait else 0.0,
-            "uncoarsen_executables": uncoarsen_level_fleet._cache_size(),
+            "uncoarsen_executables": uncoarsen_level._cache_size(),
             "compile_cache": cache_stats().snapshot(),
         }
 
 
 def serve_signatures(dispatch_log) -> set:
-    """Distinct ``uncoarsen_level_fleet`` compile signatures a serve run
-    must have hit — the §10 ``_fleet_signatures`` counting rule lifted to
-    the dispatch log: (lanes, T, fine rung, coarse rung, c, ell width, k,
-    backend).  With the fixed-lanes discipline this collapses to one
+    """Distinct ``uncoarsen_level`` compile signatures a serve run must
+    have hit — the §10 ``_fleet_signatures`` counting rule lifted to the
+    dispatch log: (lanes, T, fine rung, coarse rung, c, ell width,
+    masked, k, backend).  With the fixed-lanes discipline this collapses to one
     signature per (rung, k): lanes and T never vary within a server."""
     sigs = set()
     for d in dispatch_log:
@@ -449,5 +452,5 @@ def serve_signatures(dispatch_log) -> set:
                 c = d["c_finest"] if st["level"] == 0 else d["c_coarse"]
                 md = st.get("ell_width") if d["backend"] == "ell" else None
                 sigs.add((b["lanes"], d["trials"], st["n_max"], st["m_max"],
-                          nc, c, md, d["k"], d["backend"]))
+                          nc, c, md, st["masked"], d["k"], d["backend"]))
     return sigs

@@ -134,7 +134,7 @@ def run_workload(scfg: ServeConfig, spec: dict, *, warmup: bool = True,
     """
     from dataclasses import replace
 
-    from repro.core.partition import partition, uncoarsen_level_fleet
+    from repro.core.partition import partition, uncoarsen_level
 
     if workload is None:
         workload = build_workload(spec)
@@ -153,13 +153,13 @@ def run_workload(scfg: ServeConfig, spec: dict, *, warmup: bool = True,
                 seed=scfg.partition.seed,
             ).items() if kk != "signatures"
         }
-    execs0 = uncoarsen_level_fleet._cache_size()
+    execs0 = uncoarsen_level._cache_size()
     t0 = time.perf_counter()
     records = asyncio.run(replay_workload(server, workload))
     wall = time.perf_counter() - t0
     report["post_warmup_new_executables" if warmup
            else "new_executables"] = (
-        uncoarsen_level_fleet._cache_size() - execs0
+        uncoarsen_level._cache_size() - execs0
     )
 
     if verify:

@@ -183,8 +183,6 @@ def test_check_baseline_gate():
 
     base = {
         "baseline_tolerance": 0.05,
-        "coarsen_mode_ab": {"smoke": {"device": {"cut": 36},
-                                      "host": {"cut": 36}}},
         "trials_ab": {"smoke": {"best_cut": 36, "trial_cuts": [36, 43]}},
         "fleet_ab": {"smoke": {"cuts": {"g16": 36}, "balanced": {"g16": True}}},
     }

@@ -11,6 +11,7 @@ import pytest
 pytest.importorskip("hypothesis")  # optional dep: skip, don't die at collect
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
+from host_coarsen import coarsen_once
 from repro.core import coarsen, connectivity as cn, metrics, rebalance, refine
 from repro.core.graph import build_csr_host
 from repro.data import graphs as gen
@@ -78,7 +79,7 @@ def test_refine_output_invariants(g, k, data):
 
 @given(g=random_graph(), data=st.data())
 def test_coarsen_conservation(g, data):
-    gc, cmap = coarsen.coarsen_once(g, seed=data.draw(st.integers(0, 1000)))
+    gc, cmap = coarsen_once(g, seed=data.draw(st.integers(0, 1000)))
     assert int(gc.total_vweight()) == int(g.total_vweight())
     m = int(g.m)
     cm = np.asarray(cmap)

@@ -65,7 +65,7 @@ def test_serve_bit_identical_mixed_shape_mixed_k():
 def test_warmup_covers_replay():
     """After the AOT pass over the workload's shapes × k grid, replaying
     compiles zero new fleet executables."""
-    from repro.core.partition import uncoarsen_level_fleet
+    from repro.core.partition import uncoarsen_level
 
     server = _server()
     shapes = [gen.grid2d(6, 6), gen.grid2d(6, 5), gen.grid2d(4, 4)]
@@ -73,7 +73,7 @@ def test_warmup_covers_replay():
     assert rep["new_executables"] >= 0
     assert len(serve_signatures(server.warmup_log)) > 0
 
-    execs0 = uncoarsen_level_fleet._cache_size()
+    execs0 = uncoarsen_level._cache_size()
 
     async def run():
         async with server:
@@ -85,7 +85,7 @@ def test_warmup_covers_replay():
 
     results = asyncio.run(run())
     assert all(r.cut >= 0 for r in results)
-    assert uncoarsen_level_fleet._cache_size() == execs0, \
+    assert uncoarsen_level._cache_size() == execs0, \
         "replay after warmup must not compile new executables"
     assert serve_signatures(server.dispatch_log) <= \
         serve_signatures(server.warmup_log)

@@ -18,7 +18,7 @@ import pytest
 from repro.core import coarsen as co
 from repro.core import connectivity as cn
 from repro.core import initial, metrics, refine
-from repro.core.graph import build_csr_host
+from repro.core.graph import build_csr_host, stack_graphs, unstack_graph
 from repro.core.partition import (
     PartitionConfig, _best_trial, partition, uncoarsen_level,
 )
@@ -75,30 +75,34 @@ def test_uncoarsen_level_matches_unfused(backend):
     sequence, exactly, for every trial in the batch."""
     g = gen.grid2d(16, 16)
     k = 4
-    levels = co.multilevel_coarsen(g, coarse_target=64, seed=0)
+    levels = co.multilevel_coarsen(stack_graphs([g]), coarse_target=64,
+                                   seed=0)
     assert len(levels) >= 2
     fine, coarse = levels[-2], levels[-1]
+    fine_g, coarse_g = (unstack_graph(lv.graph, 0) for lv in (fine, coarse))
     seeds = (0, 5)
     parts_b = initial.initial_partition_batch(coarse.graph, k, seeds)
     kw = dict(k=k, lam=0.03, c=0.75, backend=backend, patience=4,
               max_iter=40, b_max=2, variant="full", rebuild_every=0)
     fused_b, stats_b = uncoarsen_level(
-        fine.graph, fine.cmap, parts_b, 0.999, **kw
+        fine.graph, fine.cmap, parts_b, None, 0.999, **kw
     )
     for t, seed in enumerate(seeds):
-        pc = initial.initial_partition(coarse.graph, k, seed=seed)
-        np.testing.assert_array_equal(np.asarray(parts_b[t]), np.asarray(pc))
+        pc = initial.initial_partition(coarse_g, k, seed=seed)
+        np.testing.assert_array_equal(np.asarray(parts_b[0, t]),
+                                      np.asarray(pc))
         # legacy unfused path: project -> mask -> build_state -> jet_refine
-        pf = co.project_partition(fine.cmap, pc)
-        pf = jnp.where(fine.graph.vertex_mask(), pf, k).astype(jnp.int32)
-        conn0 = cn.build_state(fine.graph, pf, k, backend)
+        pf = co.project_partition(fine.cmap[0], pc)
+        pf = jnp.where(fine_g.vertex_mask(), pf, k).astype(jnp.int32)
+        conn0 = cn.build_state(fine_g, pf, k, backend)
         ref, ref_stats = refine.jet_refine(
-            fine.graph, pf, k, lam=0.03, c=0.75, phi=0.999, backend=backend,
+            fine_g, pf, k, lam=0.03, c=0.75, phi=0.999, backend=backend,
             patience=4, max_iter=40, b_max=2, conn0=conn0,
         )
-        np.testing.assert_array_equal(np.asarray(fused_b[t]), np.asarray(ref))
+        np.testing.assert_array_equal(np.asarray(fused_b[0, t]),
+                                      np.asarray(ref))
         for kk in ref_stats:
-            assert int(stats_b[kk][t]) == int(ref_stats[kk]), (kk, t)
+            assert int(stats_b[kk][0, t]) == int(ref_stats[kk]), (kk, t)
 
 
 @pytest.mark.parametrize("backend", ["dense", "ell"])
@@ -108,9 +112,10 @@ def test_uncoarsen_level_conds_only_unbatched(backend):
     kind it takes; at T=2 the batched predicates turn both into selects."""
     g = gen.grid2d(16, 16)
     k = 4
-    levels = co.multilevel_coarsen(g, coarse_target=64, seed=0)
+    levels = co.multilevel_coarsen(stack_graphs([g]), coarse_target=64,
+                                   seed=0)
     fine, coarse = levels[-2], levels[-1]
-    max_degree = (int(jnp.max(fine.graph.degrees())) if backend == "ell"
+    max_degree = (int(fine.stats["max_degree"][0]) if backend == "ell"
                   else None)
     kw = dict(k=k, lam=0.03, c=0.75, backend=backend, patience=4,
               max_iter=40, b_max=2, variant="full", rebuild_every=0,
@@ -120,10 +125,23 @@ def test_uncoarsen_level_conds_only_unbatched(backend):
         parts_b = initial.initial_partition_batch(coarse.graph, k,
                                                   tuple(range(T)))
         jaxpr = jax.make_jaxpr(
-            lambda f, cm, pb: uncoarsen_level(f, cm, pb, 0.999, **kw)
+            lambda f, cm, pb: uncoarsen_level(f, cm, pb, None, 0.999, **kw)
         )(fine.graph, fine.cmap, parts_b)
         conds[T] = len(re.findall(r"\bcond\[", str(jaxpr)))
     assert conds == {1: 2, 2: 0}, conds
+
+
+def test_coarsen_level_cond_only_unbatched():
+    """A bucket of one lane coarsens unbatched, so the two-hop trigger stays
+    a ``lax.cond`` and a mesh level skips the two-hop pass; across two
+    lanes the batched predicate turns it into a select."""
+    g = gen.grid2d(16, 16)
+    conds = {}
+    for B in (1, 2):
+        jaxpr = jax.make_jaxpr(lambda gb: co.coarsen_level(gb, seed=0))(
+            stack_graphs([g] * B))
+        conds[B] = len(re.findall(r"\bcond\[", str(jaxpr)))
+    assert conds == {1: 1, 2: 0}, conds
 
 
 @pytest.mark.parametrize("backend", ["dense", "sorted", "ell"])
@@ -177,10 +195,11 @@ def test_initial_partition_batch_matches_scalar():
     g = gen.grid2d(10, 10)
     seeds = (0, 1, 7)
     for method in ("voronoi", "random"):
-        batch = initial.initial_partition_batch(g, 5, seeds, method=method)
+        batch = initial.initial_partition_batch(stack_graphs([g]), 5, seeds,
+                                                method=method)
         for t, s in enumerate(seeds):
             np.testing.assert_array_equal(
-                np.asarray(batch[t]),
+                np.asarray(batch[0, t]),
                 np.asarray(initial.initial_partition(g, 5, seed=s,
                                                      method=method)),
             )
