@@ -22,6 +22,12 @@ and differs only in recounting the part sizes.
 All three iteration kinds consume the same ``ConnQueries`` computed once
 per iteration from the threaded state.
 
+Jetlp's afterburner reads each edge endpoint's (gain, destination, part)
+with one gather, so an iteration holds two M-length gathers, not eight;
+the ``X`` masks at both endpoints drop out exactly, and the ``segment_sum``
+over ``esrc`` does not claim sorted indices (padding edges carry
+``esrc = 0``); see ``jetlp_moves``.
+
 Deviations from the paper are documented in DESIGN.md §6; the functional
 behaviour (filters, afterburner ordering, locking, best-partition tracking
 with the phi tolerance) follows the paper line by line.
@@ -85,6 +91,20 @@ def jetlp_moves(
 
     ``queries`` is the shared per-iteration ConnQueries from the threaded
     state; standalone callers may omit it and pay for a one-off build.
+
+    The afterburner reads each directed edge's head ``u = adjncy`` and tail
+    ``v = esrc`` with one gather each, from one ``(N, 3)`` table of
+    ``(F, Pd, parts)``: two M-length gathers where one per field and
+    endpoint took eight (on a v5e a row gather of three int32s costs a
+    fifth of one scalar gather of the same indices, PERF.md §5).  Two
+    masks of the paper's form drop
+    out exactly.  ``X[v]``: ``move = X & (F2 >= 0)`` discards every vertex
+    outside X, so what the edges of such a ``v`` sum to is never read.
+    ``X[u]``: outside X, ``Pd[u] == parts[u]``, so ``u`` moving "first"
+    leaves its part where it is.  The ``segment_sum`` over ``esrc`` does
+    not claim ``indices_are_sorted``: padding edges carry ``esrc = 0``
+    after the real ones (``graph.build_csr_host``, ``csr_from_edge_runs``,
+    ``Graph.with_capacity``), so ``esrc`` is not sorted.
     """
     use_ratio, use_ab, use_locks = variant_flags(variant)
     vmask = g.vertex_mask()
@@ -105,18 +125,21 @@ def jetlp_moves(
     if not use_ab:
         return X, Pd
 
-    # Afterburner: per-edge approximate next state.
-    u, v, w = g.adjncy, g.esrc, g.adjwgt
-    Fu = F[u]
-    Fv = F[v]
+    # Afterburner: per-edge approximate next state, each endpoint's
+    # (gain, destination, part) read by one gather.
+    u, v = g.adjncy, g.esrc
+    state = jnp.stack([F, Pd, parts], axis=1)
+    head, tail = state[u], state[v]
+    Fu, Pdu, pu0 = head[:, 0], head[:, 1], head[:, 2]
+    Fv, Pdv, pv = tail[:, 0], tail[:, 1], tail[:, 2]
     # ord(u) < ord(v): u moves "first" iff higher priority gain, tie -> smaller id
-    u_first = X[u] & ((Fu > Fv) | ((Fu == Fv) & (u < v)))
-    pu = jnp.where(u_first, Pd[u], parts[u])
-    contrib = w * (
-        (pu == Pd[v]).astype(jnp.int32) - (pu == parts[v]).astype(jnp.int32)
+    u_first = (Fu > Fv) | ((Fu == Fv) & (u < v))
+    pu = jnp.where(u_first, Pdu, pu0)
+    contrib = g.adjwgt * (
+        (pu == Pdv).astype(jnp.int32) - (pu == pv).astype(jnp.int32)
     )
     F2 = jax.ops.segment_sum(
-        jnp.where(g.edge_mask() & X[v], contrib, 0), v, num_segments=g.n_max
+        jnp.where(g.edge_mask(), contrib, 0), v, num_segments=g.n_max
     )
     move = X & (F2 >= 0)
     return move, Pd

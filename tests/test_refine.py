@@ -1,8 +1,12 @@
 """Jetlp / Jetr / full Jet refinement behaviour tests."""
+import re
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import connectivity as cn
 from repro.core import metrics, rebalance, refine
 from repro.core.graph import build_csr_host
 from repro.core.partition import PartitionConfig, partition, refine_only
@@ -41,6 +45,132 @@ def test_jetlp_respects_locks():
     lock = jnp.ones((g.n_max,), bool)
     move, _ = refine.jetlp_moves(g, parts, k, lock, c=0.25)
     assert int(jnp.sum(move.astype(jnp.int32))) == 0
+
+
+def _jetlp_per_field(g, parts, k, lock, c, variant, q):
+    """Alg 4.2 with the afterburner reading one field per gather: ``F``,
+    ``X``, ``Pd`` and ``parts`` at each endpoint, eight M-length gathers.
+    Returns (move, dest, X)."""
+    use_ratio, use_ab, use_locks = refine.variant_flags(variant)
+    F = q.best_conn - q.conn_self
+    if use_ratio:
+        thr = jnp.floor(c * q.conn_self.astype(jnp.float32)).astype(jnp.int32)
+        filter1 = (F >= 0) | (-F < thr)
+    else:
+        filter1 = F >= 0
+    X = g.vertex_mask() & (q.best_conn > 0) & filter1
+    if use_locks:
+        X = X & ~lock
+    Pd = jnp.where(X, q.best_part, parts)
+    if not use_ab:
+        return X, Pd, X
+    u, v, w = g.adjncy, g.esrc, g.adjwgt
+    Fu, Fv = F[u], F[v]
+    u_first = X[u] & ((Fu > Fv) | ((Fu == Fv) & (u < v)))
+    pu = jnp.where(u_first, Pd[u], parts[u])
+    contrib = w * (
+        (pu == Pd[v]).astype(jnp.int32) - (pu == parts[v]).astype(jnp.int32)
+    )
+    F2 = jax.ops.segment_sum(
+        jnp.where(g.edge_mask() & X[v], contrib, 0), v, num_segments=g.n_max
+    )
+    return X & (F2 >= 0), Pd, X
+
+
+def _padded_weighted_graph(seed, n=90, n_max=128, m_pad=37):
+    """A connected weighted graph padded past both its vertex and edge
+    counts (padding edges carry ``esrc = 0``, so ``esrc`` is unsorted)."""
+    rng = np.random.default_rng(seed)
+    ring = np.stack([np.arange(n), (np.arange(n) + 1) % n], axis=1)
+    extra = rng.integers(0, n, size=(3 * n, 2))
+    edges = np.concatenate([ring, extra])
+    wts = rng.integers(1, 5, size=len(edges))
+    m = build_csr_host(n, edges, wts).adjncy.shape[0]
+    return build_csr_host(n, edges, wts, n_max=n_max, m_max=m + m_pad)
+
+
+def _tied_queries(g, parts, k, rng):
+    """ConnQueries with gains in [-7, 3]: ties in ``F`` on most edges, and
+    negative gains that filter 1 admits (``-F < floor(c * conn_self)``)."""
+    vm = np.asarray(g.vertex_mask())
+    p = np.asarray(parts)
+    conn_self = np.where(vm, rng.integers(0, 8, g.n_max), 0)
+    best_conn = np.where(vm, rng.integers(0, 4, g.n_max), 0)
+    other = (p + 1 + rng.integers(0, max(k - 1, 1), g.n_max)) % k
+    best_part = np.where(vm & (best_conn > 0), other, k)
+    return cn.ConnQueries(*(jnp.asarray(a.astype(np.int32))
+                            for a in (conn_self, best_part, best_conn)))
+
+
+@pytest.mark.parametrize("k", [2, 8, 64])
+@pytest.mark.parametrize("variant", list(refine.VARIANTS))
+def test_jetlp_moves_equal_per_field_afterburner(variant, k):
+    """``jetlp_moves`` returns the per-field afterburner's ``move`` and
+    ``dest`` bit for bit: on a padded weighted graph with random locks,
+    under the graph's own queries and under queries with forced ties in
+    ``F`` and admitted negative gains, and vmapped over two trials."""
+    g = _padded_weighted_graph(seed=k)
+    rng = np.random.default_rng(100 + k)
+    c = 0.75
+    cases = []
+    for _ in range(2):
+        parts = _rand_parts(g, k, seed=int(rng.integers(1 << 30)))
+        lock = jnp.asarray(rng.random(g.n_max) < 0.3)
+        cases.append((parts, lock, cn.queries(g, parts, k)))
+        cases.append((parts, lock, _tied_queries(g, parts, k, rng)))
+    use_ratio, use_ab, _ = refine.variant_flags(variant)
+    tied_edges = rejected = admitted_negative = 0
+    for parts, lock, q in cases:
+        move, dest = refine.jetlp_moves(g, parts, k, lock, c,
+                                        variant=variant, queries=q)
+        ref_move, ref_dest, X = _jetlp_per_field(g, parts, k, lock, c,
+                                                 variant, q)
+        np.testing.assert_array_equal(np.asarray(move), np.asarray(ref_move))
+        np.testing.assert_array_equal(np.asarray(dest), np.asarray(ref_dest))
+        F = np.asarray(q.best_conn - q.conn_self)
+        Xn, em = np.asarray(X), np.asarray(g.edge_mask())
+        u, v = np.asarray(g.adjncy), np.asarray(g.esrc)
+        tied_edges += int(np.sum(em & Xn[u] & Xn[v] & (F[u] == F[v])))
+        rejected += int(np.sum(Xn & ~np.asarray(move)))
+        admitted_negative += int(np.sum(Xn & (F < 0)))
+    assert tied_edges > 0
+    assert (rejected > 0) == use_ab
+    assert (admitted_negative > 0) == use_ratio
+
+    # two trials in one vmapped call, as the trial axis runs them
+    parts, lock, q = (jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), a, b)
+                      for a, b in zip(cases[1], cases[3]))
+    move, dest = jax.vmap(
+        lambda p, lk, qq: refine.jetlp_moves(g, p, k, lk, c, variant=variant,
+                                             queries=qq))(parts, lock, q)
+    for t, (p1, lk1, q1) in enumerate((cases[1], cases[3])):
+        ref_move, ref_dest, _ = _jetlp_per_field(g, p1, k, lk1, c, variant, q1)
+        np.testing.assert_array_equal(np.asarray(move[t]), np.asarray(ref_move))
+        np.testing.assert_array_equal(np.asarray(dest[t]), np.asarray(ref_dest))
+
+
+def _m_row_gathers(fn, g, *args):
+    """Gathers with an M-long output in ``fn``'s compiled CPU HLO."""
+    hlo = jax.jit(fn).lower(g, *args).compile().as_text()
+    shapes = re.findall(r"= \w+\[([\d,]+)\][^\n]*? gather\(", hlo)
+    return sum(str(g.m_max) in dims.split(",") for dims in shapes)
+
+
+def test_afterburner_gathers_each_endpoint_once():
+    """The afterburner reads each edge's head and tail state with one
+    gather each: 2 M-length gathers, where one gather per field took 8."""
+    g = _padded_weighted_graph(seed=1)
+    k = 8
+    parts = _rand_parts(g, k, seed=2)
+    lock = jnp.zeros((g.n_max,), bool)
+    q = cn.queries(g, parts, k)
+    packed = _m_row_gathers(
+        lambda g, p, lk, q: refine.jetlp_moves(g, p, k, lk, 0.75, queries=q),
+        g, parts, lock, q)
+    per_field = _m_row_gathers(
+        lambda g, p, lk, q: _jetlp_per_field(g, p, k, lk, 0.75, "full", q)[:2],
+        g, parts, lock, q)
+    assert (packed, per_field) == (2, 8)
 
 
 @pytest.mark.parametrize("mode", ["weak", "strong"])
